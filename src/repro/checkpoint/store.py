@@ -33,9 +33,7 @@ _COMMIT = "COMMITTED"
 
 
 def _flatten(tree: Any) -> tuple[list[tuple[str, Any]], Any]:
-    from repro.compat import tree_flatten_with_path
-
-    leaves, treedef = tree_flatten_with_path(tree)
+    leaves, treedef = jax.tree.flatten_with_path(tree)
     out = []
     for path, leaf in leaves:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -122,7 +120,7 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((qb, 128), jnp.float32),
             pltpu.VMEM((qb, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

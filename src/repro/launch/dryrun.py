@@ -1,9 +1,12 @@
 import os
 
-from repro.launch.xla_flags import merged_flags
-
-os.environ["XLA_FLAGS"] = merged_flags("dryrun", os.environ.get("XLA_FLAGS", ""),
-                                       platform="cpu")
+# jax fixes the host device count when its backend starts, so the fake
+# 512-chip topology must be in XLA_FLAGS before jax is imported; a count
+# the caller already set wins
+_flags = os.environ.get("XLA_FLAGS", "")
+if "--xla_force_host_platform_device_count" not in _flags:
+    os.environ["XLA_FLAGS"] = (
+        f"{_flags} --xla_force_host_platform_device_count=512".strip())
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -14,9 +17,7 @@ compiled artifact yields ``memory_analysis()`` (fits-in-HBM proof) and
 ``cost_analysis()`` + HLO collectives (roofline terms, §Roofline).
 
 The ``XLA_FLAGS`` assignment above MUST stay first (before any jax
-import): jax locks the device count on first initialization.  The flag
-set itself (``--xla_force_host_platform_device_count=512``) lives in
-``repro.launch.xla_flags`` with the other tuned per-platform profiles.
+import): jax locks the device count on first initialization.
 
 Usage:
     python -m repro.launch.dryrun --arch granite-3-2b --shape train_4k

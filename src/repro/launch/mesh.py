@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 # TPU v5e per-chip constants used by the roofline analysis
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -19,28 +20,10 @@ HBM_BW = 819e9                    # bytes/s
 ICI_BW_PER_LINK = 50e9            # bytes/s/link
 
 
-def _auto(n: int):
-    """``(AxisType.Auto,) * n`` on jax >= 0.5, None on older releases
-    (whose ``jax.make_mesh`` has no ``axis_types`` parameter)."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return None
-    return (AxisType.Auto,) * n
-
-
-def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh`` with explicit-Auto axis types."""
-    axis_types = _auto(len(axes))
-    if axis_types is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=axis_types)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_elastic_mesh(n_lost_hosts: int = 0, *, chips_per_host: int = 4,
@@ -56,8 +39,10 @@ def make_elastic_mesh(n_lost_hosts: int = 0, *, chips_per_host: int = 4,
     model = 16
     data = 1 << int(np.floor(np.log2(max(total // model, 1))))
     if multi_pod and data >= 32:
-        return make_mesh((2, data // 2, model), ("pod", "data", "model"))
-    return make_mesh((data, model), ("data", "model"))
+        shape, axes = (2, data // 2, model), ("pod", "data", "model")
+    else:
+        shape, axes = (data, model), ("data", "model")
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_chip_count(mesh) -> int:

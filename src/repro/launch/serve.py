@@ -13,6 +13,12 @@ Continuous batching with SLO admission and autoscaling::
 ``--decode sim`` swaps the jax model for the deterministic simulated
 backend on a virtual clock: a minute of traffic replays byte-identically
 in milliseconds, which is how the serving benchmarks and chaos tests run.
+
+``--full`` serves the configuration at its published widths in place of
+the reduced smoke variant (one replica per device on a multi-chip host)::
+
+    python -m repro.launch.serve --full --decode jax --prompt-len 64 \
+        --new-tokens 16 --replicas 2 --max-batch 8
 """
 from __future__ import annotations
 
@@ -21,21 +27,19 @@ import json
 
 import numpy as np
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.engine.scheduler import SCHEDULERS, make_scheduler
-from repro.launch.xla_flags import apply_xla_flags
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import (ReplicaAutoscaler, Request, SLOAdmissionPolicy,
                          WrathServeDriver)
 
 
-def main() -> None:
-    # tuned compiler flags (repro.launch.xla_flags) must be in the
-    # environment before the jax backend initializes — importing jax
-    # above does not initialize it, the first computation does
-    apply_xla_flags("serve")
+def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b",
                     help=f"one of {', '.join(a.replace('_', '-') for a in ARCH_IDS)}")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths instead of the smoke config")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=6)
@@ -61,11 +65,12 @@ def main() -> None:
     ap.add_argument("--decode", default="jax", choices=("jax", "sim"),
                     help="decode backend; 'sim' runs the modeled-cost "
                          "backend on a virtual clock (deterministic)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     continuous = (args.continuous or args.arrival_rate is not None
                   or args.autoscale is not None)
 
-    cfg = get_smoke_config(args.arch)
+    enable_compile_cache()
+    cfg = (get_config if args.full else get_smoke_config)(args.arch)
     clock = None
     if args.decode == "sim":
         from repro.sim import VirtualClock
@@ -81,7 +86,9 @@ def main() -> None:
         cfg, n_replicas=args.replicas, max_batch=args.max_batch,
         seed=args.seed, clock=clock, decode=args.decode, policy=policy,
         scheduler=make_scheduler(args.scheduler) if args.scheduler else None,
-        admission=SLOAdmissionPolicy() if args.deadline_ms else None)
+        admission=SLOAdmissionPolicy() if args.deadline_ms else None,
+        # room for prompt and output, so one batch's cache ring never wraps
+        max_len=args.prompt_len + args.new_tokens)
     rng = np.random.default_rng(args.seed)
     deadline_s = args.deadline_ms / 1e3 if args.deadline_ms else None
     reqs = [Request(rid=i,

@@ -6,6 +6,12 @@ hosts with failure injection):
     python -m repro.launch.train --arch granite-3-2b --steps 200 \
         --inject host_down:50:host01 --inject nan:80
 
+``--full`` trains the configuration at its published widths instead of
+the reduced smoke variant; ``--layers`` then cuts its depth::
+
+    python -m repro.launch.train --full --layers 4 --seq 1024 --steps 4 \
+        --inject host_down:2:host01 --ckpt-dir /path/to/fresh/dir
+
 For production-mesh work use the dry-run launcher
 (``python -m repro.launch.dryrun``), which lowers/compiles the same
 ``build_train_step`` against the 16×16 / 2×16×16 meshes.
@@ -15,12 +21,12 @@ from __future__ import annotations
 import argparse
 import json
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.engine.policies import WrathPolicy, replay
 from repro.engine.scheduler import SCHEDULERS, make_scheduler
-from repro.launch.xla_flags import apply_xla_flags
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import OptConfig
-from repro.train import TrainEvent, WrathTrainSupervisor
+from repro.train import TrainEvent, TrainReport, WrathTrainSupervisor
 
 
 def parse_event(spec: str) -> TrainEvent:
@@ -33,23 +39,24 @@ def parse_event(spec: str) -> TrainEvent:
     return TrainEvent(step=step, kind=kind, host=host, factor=factor)
 
 
-def main() -> None:
-    # tuned compiler flags (repro.launch.xla_flags) must be in the
-    # environment before the jax backend initializes — importing jax
-    # above does not initialize it, the first computation does
-    apply_xla_flags("train")
+def main(argv: list[str] | None = None) -> TrainReport:
+    """Run the supervised job that ``argv`` describes; prints and
+    returns its report."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b",
                     help=f"one of {', '.join(a.replace('_', '-') for a in ARCH_IDS)}")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths instead of the smoke config")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--hosts", type=int, default=4)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--d-model", type=int, default=0,
-                    help="override the smoke config width")
+                    help="override the config width")
     ap.add_argument("--layers", type=int, default=0)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--ckpt-dir", default="/tmp/wrath_train")
+    ap.add_argument("--ckpt-dir", default="/tmp/wrath_train",
+                    help="resumes from the latest checkpoint already here")
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--inject", action="append", default=[],
                     help="failure event kind:step[:host[:factor]] (repeatable)")
@@ -61,9 +68,10 @@ def main() -> None:
                          "shard gets N attempts before WRATH's taxonomy "
                          "is even consulted (0 = WRATH stack only)")
     ap.add_argument("--json", action="store_true", help="machine-readable report")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch)
+    enable_compile_cache()
+    cfg = (get_config if args.full else get_smoke_config)(args.arch)
     overrides = {}
     if args.d_model:
         overrides["d_model"] = args.d_model
@@ -94,7 +102,7 @@ def main() -> None:
             "restores": rep.restores, "speculations": rep.speculations,
             "denylisted": rep.denylisted, "recoveries": rep.recoveries,
         }, indent=1))
-        return
+        return rep
     print(f"{cfg.name}: {rep.steps_completed} steps, "
           f"loss {rep.losses[0]:.3f} -> {rep.losses[-1]:.3f}")
     print(f"restores={rep.restores} speculations={rep.speculations} "
@@ -102,6 +110,7 @@ def main() -> None:
     for r in rep.recoveries:
         print(f"  step {r['step']:4d} {r['error']:26s} {r['host']:8s} "
               f"-> {r['action']} (rung {r['rung']})")
+    return rep
 
 
 if __name__ == "__main__":

@@ -19,6 +19,7 @@ import dataclasses
 import re
 from typing import Any
 
+import jax
 import numpy as np
 
 from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
@@ -82,11 +83,9 @@ def hlo_cost(compiled: Any) -> dict[str, float]:
 
 def active_param_count(cfg: ModelConfig, defs: Any) -> tuple[int, int]:
     """(total_params, active_params): routed experts count as top_k/E."""
-    from repro.compat import tree_flatten_with_path
-
     total = 0
     active = 0.0
-    for path, d in tree_flatten_with_path(defs, is_leaf=is_def)[0]:
+    for path, d in jax.tree.flatten_with_path(defs, is_leaf=is_def)[0]:
         n = int(np.prod(d.shape)) if d.shape else 1
         total += n
         if cfg.moe and "experts" in d.axes:

@@ -10,7 +10,8 @@ rest of the batch, no head-of-line blocking behind the longest request.
 Two decode backends share the slot protocol:
 
 * :class:`JaxDecodeBackend` — the real model: one device-resident KV
-  cache per replica sized ``(max_batch, max_len)``, one jitted
+  cache per replica sized ``(max_batch, max_len)``, each replica on its
+  own chip where the host has several, one jitted
   ``decode_step`` program reused every step (ring-buffer cache, so the
   program never recompiles as requests come and go).  A request joining
   mid-flight is teacher-forced through its prompt (plus any tokens
@@ -117,12 +118,19 @@ class DecodeBackend:
 
 
 class JaxDecodeBackend(DecodeBackend):
-    """Real decode: one padded program + one resident cache per replica."""
+    """Real decode: one padded program + one resident cache per replica.
+
+    The i-th replica started lives on ``devices[i % len(devices)]``
+    (default ``jax.devices()``): its cache is allocated there and the
+    params are copied to that device when its first replica starts, so a
+    lost chip takes only its own replicas with it.  On one chip every
+    replica shares the one copy of the params.
+    """
 
     name = "jax"
 
     def __init__(self, cfg: Any, *, max_batch: int, seed: int = 0,
-                 max_len: int = 64):
+                 max_len: int = 64, devices: list | None = None):
         import jax
 
         from repro.models import decode_step, materialize, param_defs
@@ -130,25 +138,44 @@ class JaxDecodeBackend(DecodeBackend):
         self.cfg = cfg
         self.max_batch = max_batch
         self.max_len = max_len
-        self.params = materialize(param_defs(cfg), jax.random.PRNGKey(seed))
+        self.devices = list(devices) if devices is not None else jax.devices()
+        self.params = jax.device_put(
+            materialize(param_defs(cfg), jax.random.PRNGKey(seed)),
+            self.devices[0])
+        self._params = {self.devices[0]: self.params}
         # ONE program for every replica and every occupancy: shapes are
         # pinned to (max_batch, 1), so slot churn never recompiles
         self._decode = jax.jit(lambda p, c, b: decode_step(p, c, b, cfg))
         self._caches: dict[str, Any] = {}
+        # a restored replica keeps the device it was first given
+        self._placement: dict[str, Any] = {}
 
     def start_replica(self, replica: Any) -> None:
         import jax
 
         from repro.models import cache_defs, materialize
 
-        self._caches[replica.name] = materialize(
+        dev = self._placement.setdefault(
+            replica.name,
+            self.devices[len(self._placement) % len(self.devices)])
+        if dev not in self._params:
+            self._params[dev] = jax.device_put(self.params, dev)
+        self._caches[replica.name] = jax.device_put(materialize(
             cache_defs(self.cfg, self.max_batch, self.max_len),
-            jax.random.PRNGKey(0))
+            jax.random.PRNGKey(0)), dev)
 
     def drop_replica(self, name: str) -> None:
         self._caches.pop(name, None)
 
+    def cache_devices(self) -> dict[str, Any]:
+        """Live replica name -> the device that holds its decode cache."""
+        import jax
+
+        return {name: next(iter(jax.tree.leaves(cache)[0].devices()))
+                for name, cache in self._caches.items()}
+
     def step(self, replica: Any, inputs: list[int | None]) -> list[int]:
+        import jax
         import jax.numpy as jnp
         import numpy as np
 
@@ -164,8 +191,9 @@ class JaxDecodeBackend(DecodeBackend):
         for i, tok in enumerate(inputs):
             if tok is not None:
                 toks[i, 0] = tok
-        logits, cache = self._decode(self.params, cache,
-                                     {"inputs": jnp.asarray(toks)})
+        dev = self._placement[replica.name]
+        logits, cache = self._decode(self._params[dev], cache,
+                                     {"inputs": jax.device_put(toks, dev)})
         self._caches[replica.name] = cache
         nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
         return [int(nxt[i]) for i in range(self.max_batch)]

@@ -278,9 +278,7 @@ class WrathTrainSupervisor:
             step_events = by_step.pop(step, [])
             for ev in step_events:
                 node = self.cluster.find_node(ev.host) if ev.host else None
-                if ev.kind == "host_down" and node:
-                    node.shutdown_hardware()
-                elif ev.kind == "host_up" and node:
+                if ev.kind == "host_up" and node:
                     node.restore_hardware()
                     self.denylist.discard(node.name)
                 elif ev.kind == "straggler" and node:
@@ -312,6 +310,14 @@ class WrathTrainSupervisor:
             edges = np.cumsum([0] + sizes)
             shards = [np.arange(edges[i], edges[i + 1])
                       for i in range(len(hosts))]
+            # a host lost during the step: its shard is already planned, so
+            # the loss surfaces as that shard's failure and goes through the
+            # policy stack; the next step's plan leaves the host out
+            for ev in step_events:
+                if ev.kind == "host_down" and ev.host:
+                    node = self.cluster.find_node(ev.host)
+                    if node:
+                        node.shutdown_hardware()
 
             grads_acc = None
             loss_acc = 0.0

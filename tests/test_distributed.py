@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
@@ -21,10 +20,9 @@ from repro.optim.compress import compress_int8, decompress_int8
 
 @pytest.fixture(scope="module")
 def mesh2d():
-    from repro.launch.mesh import make_mesh
-    # 1 real device is fine: mesh construction only needs shape (1,1) —
-    # use abstract mesh via jax.sharding.Mesh over the single device
-    return make_mesh((1, 1), ("data", "model"))
+    # 1 real device is fine: mesh construction only needs shape (1,1)
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 class FakeMesh:

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config

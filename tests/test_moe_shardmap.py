@@ -20,7 +20,6 @@ SCRIPT = textwrap.dedent("""
     from repro.models.moe import make_moe_defs, moe_gshard, moe_shard_map
     from repro.models.spec import materialize
     from repro.distributed import activation_sharding, ACT_RULES
-    from repro.launch.mesh import make_mesh
 
     cfg = get_smoke_config("olmoe_1b_7b")
     cfg = dataclasses.replace(cfg, compute_dtype="float32",
@@ -33,7 +32,8 @@ SCRIPT = textwrap.dedent("""
                           params)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                           jnp.float32)
-    mesh = make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with mesh, activation_sharding(mesh, ACT_RULES):
         y_sm, _ = jax.jit(lambda p, xx: moe_shard_map(p, xx, cfg))(params, x)
     y_ref, _ = moe_gshard(params, x, cfg)

@@ -1,14 +1,15 @@
 """Deterministic simulation plane: virtual clock, scenario DSL, seeded
 chaos campaigns, determinism regression, and WRATH-specific properties.
 
-The chaos property holds under *any* seed; with ``hypothesis`` installed
-the seed space is explored adaptively, otherwise a fixed seeded sweep
-runs — either way the failing seed is printed and reproduces the run
-exactly (``run_scenario(Scenario.random(seed))``).
+The chaos property holds under *any* seed; ``hypothesis`` explores the
+seed space adaptively, and the failing seed is printed and reproduces
+the run exactly (``run_scenario(Scenario.random(seed))``).
 """
 import json
 
 import pytest
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
 from repro.engine.events import EventLoop
 from repro.engine.policies import ProactivePolicy, WrathPolicy
@@ -21,14 +22,6 @@ from repro.sim import (
     campaign,
     run_scenario,
 )
-
-try:
-    from hypothesis import HealthCheck, given, settings
-    from hypothesis import strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:                      # pragma: no cover - optional dep
-    HAVE_HYPOTHESIS = False
-
 
 # --------------------------------------------------------------------- #
 # virtual clock + event loop basics
@@ -322,14 +315,8 @@ def _assert_campaign_property(seed: int) -> None:
         f"nondeterminism for seed={seed}")
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=25, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(seed=st.integers(min_value=0, max_value=2**32 - 1))
-    def test_chaos_property_any_seed(seed):
-        _assert_campaign_property(seed)
-else:                                    # seeded fallback sweep
-    @pytest.mark.parametrize("seed", [3, 17, 404, 9_001, 123_456,
-                                      2**31 - 1])
-    def test_chaos_property_any_seed(seed):
-        _assert_campaign_property(seed)
+@settings(max_examples=25, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+@given(seed=st.integers(min_value=0, max_value=2**32 - 1))
+def test_chaos_property_any_seed(seed):
+    _assert_campaign_property(seed)

@@ -34,6 +34,10 @@ def test_host_loss_elastic_remesh(tmp_path):
     rep = sup.run(20, events=[TrainEvent(step=5, kind="host_down",
                                          host="host01")])
     assert rep.final_hosts == 2          # re-meshed to surviving hosts
+    # the host is lost during step 5: its shard fails and is recovered
+    assert [(r["step"], r["host"], r["error"]) for r in rep.recoveries] == [
+        (5, "host01", "HardwareShutdownError")]
+    assert rep.recovered_all
     assert rep.steps_completed == 20
     assert rep.losses[-1] < rep.losses[0]
 

@@ -51,7 +51,6 @@ SYSTEM_EVENTS = frozenset({
 
 GAUGES = frozenset({
     'serve.queue_depth',
-    'serve.slot_occupancy',
 })
 
 #: dynamic-name families (``f"fault_{kind}"`` …); members are closed

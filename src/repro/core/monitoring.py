@@ -25,18 +25,24 @@ Components:
 * :class:`TaskMonitoringAgent` — per-node agent sampling resource usage of
   the running workers (psutil-based, as §VI-B) plus simulated node state.
 * :class:`SystemMonitoringAgent` — heartbeat emitter for any component.
+* :class:`SpanLog` — timed host spans of the serve plane and decode step
+  (``MonitoringDatabase.spans``), off by default.
 
 Memory bounds: every store (task events per task, system events, failure
 reports, resource profiles per node, heartbeat-interval samples) is a ring
-capped at ``retention`` entries; streaming profiles are O(1) per key.
+capped at ``retention`` entries, and spans a ring of their own; streaming
+profiles are O(1) per key.
 """
 from __future__ import annotations
 
+import contextlib
+import itertools
 import json
 import math
 import socket
 import socketserver
 import threading
+import time
 from collections import defaultdict, deque
 from dataclasses import asdict, dataclass, field, is_dataclass
 from typing import Any
@@ -250,6 +256,116 @@ class NodeHealth:
 
 
 # --------------------------------------------------------------------------
+# Host spans
+# --------------------------------------------------------------------------
+
+
+#: the context manager a disabled :class:`SpanLog` hands out
+_NO_SPAN = contextlib.nullcontext()
+#: spans a :class:`SpanLog` keeps, newest last: a whole traced window of a
+#: serve cell (about 4,000 spans per batch of 96 requests over 400 decode
+#: steps), where the monitor's ``retention`` keeps only recent history
+SPAN_RING = 1 << 15
+
+
+class _Span:
+    __slots__ = ("log", "name", "attrs", "id", "parent", "t0", "mark")
+
+    def __init__(self, log: "SpanLog", name: str, attrs: dict[str, Any]):
+        self.log = log
+        self.name = name
+        self.attrs = attrs
+        self.mark = None
+
+    def __enter__(self) -> "_Span":
+        log = self.log
+        stack = log._stack()
+        self.parent = stack[-1] if stack else None
+        self.id = next(log._ids)
+        stack.append(self.id)
+        if log._annotation is not None:
+            self.mark = log._annotation(self.name)
+            self.mark.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        t1 = time.perf_counter()
+        if self.mark is not None:
+            self.mark.__exit__(None, None, None)
+        log = self.log
+        log._stack().pop()
+        log._ring.append((self.id, self.name, self.t0, t1, self.parent,
+                          self.attrs))
+
+
+class SpanLog:
+    """Timed host spans, kept in memory and read after a run.
+
+    Off by default: ``span()`` then returns one shared no-op context
+    manager, so an instrumented call costs an attribute check.  Once
+    enabled, each span records its name, its start and end on
+    ``time.perf_counter()`` (the clock a benchmark times steps on), the id
+    of the span open around it on the same thread (its parent), and the
+    attributes it was given (``rid``, ``replica``, ``step``).  With
+    ``annotate`` each span is also a ``jax.profiler.TraceAnnotation`` of
+    the same name, so it shows in a profiler trace on the device's clock.
+
+    The ring keeps the last ``SPAN_RING`` spans.  Spans never become
+    monitor events, so the simulation plane's event traces do not see
+    them; under a virtual clock the log cannot be turned on, since its
+    times are real.
+    """
+
+    def __init__(self, *, virtual: bool = False) -> None:
+        self.enabled = False
+        self._virtual = virtual
+        self._annotation: Any = None
+        # one id per span opened, in opening order; ``next`` on a count
+        # and ``deque.append`` are atomic, so threads need no lock here
+        self._ids = itertools.count()
+        self._ring: deque[tuple] = deque(maxlen=SPAN_RING)
+        self._local = threading.local()
+
+    def _stack(self) -> list[int]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def enable(self, annotate: bool = False) -> None:
+        """Start recording; with ``annotate``, into the profiler too."""
+        if self._virtual:
+            raise ValueError("spans are timed on the real clock; the log "
+                             "stays off under a virtual clock")
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        else:
+            self._annotation = None
+        self.enabled = True
+
+    def span(self, name: str, **attrs: Any) -> Any:
+        """A context manager timing one span (a no-op while off)."""
+        if not self.enabled:
+            return _NO_SPAN
+        return _Span(self, name, attrs)
+
+    def record(self, name: str, t0: float, t1: float, **attrs: Any) -> None:
+        """Add a span that has already ended (``perf_counter`` times).  It
+        has no parent: it began before whatever span is open now."""
+        if self.enabled:
+            self._ring.append((next(self._ids), name, t0, t1, None, attrs))
+
+    def export(self) -> list[dict[str, Any]]:
+        """Every retained span as a plain dict, in the order they ended:
+        ``id``, ``name``, ``start``, ``end``, ``parent`` (an ``id`` or
+        None), then the span's attributes."""
+        return [{"id": i, "name": n, "start": t0, "end": t1, "parent": p,
+                 **attrs} for i, n, t0, t1, p, attrs in list(self._ring)]
+
+
+# --------------------------------------------------------------------------
 # Centralized monitoring database
 # --------------------------------------------------------------------------
 
@@ -320,13 +436,14 @@ class MonitoringDatabase:
             lambda: defaultdict(PlacementStats))
         self._pool_history: dict[str, dict[str, PlacementStats]] = defaultdict(
             lambda: defaultdict(PlacementStats))
-        # named scalar gauges (serving-plane queue depth, slot occupancy):
+        # named scalar gauges (serving-plane queue depth):
         # streaming stats for the long view + a timestamped ring of recent
         # samples for trend queries ("has the queue grown for K ticks?")
         self._gauges: dict[str, StreamingStats] = defaultdict(
             lambda: StreamingStats(sample_cap=64))
         self._gauge_rings: dict[str, deque[tuple[float, float]]] = defaultdict(
             lambda: deque(maxlen=retention))
+        self.spans = SpanLog(virtual=bool(getattr(clock, "virtual", False)))
 
     # -- ingest (radio entry point) ----------------------------------------
     def ingest(self, message: dict[str, Any]) -> None:
@@ -433,8 +550,8 @@ class MonitoringDatabase:
             self.failures.append(report)
 
     def record_gauge(self, name: str, value: float) -> None:
-        """Observe one sample of a named scalar gauge (queue depth, slot
-        occupancy, live replicas).  O(1); ring-bounded like every store."""
+        """Observe one sample of a named scalar gauge (queue depth, live
+        replicas).  O(1); ring-bounded like every store."""
         with self._lock:
             value = float(value)
             self._gauges[name].push(value)

@@ -137,8 +137,6 @@ class EventLoop:
         if not self.clock.virtual:
             self._thread = threading.Thread(target=self._run, daemon=True,
                                             name=name)
-        # observability: how many events have executed, by name
-        self.dispatched: dict[str, int] = {}
         # optional hook observing swallowed callback exceptions (the DFK
         # records them as system events so watcher bugs stay visible)
         self.on_error = on_error
@@ -267,7 +265,6 @@ class EventLoop:
                     self.on_error(ev.name, e)
                 except Exception:  # noqa: BLE001 - hook bugs stay contained
                     pass
-        self.dispatched[ev.name] = self.dispatched.get(ev.name, 0) + 1
         if ev.period is not None and not ev.cancelled:
             with self._cond:
                 if not self._stopped:

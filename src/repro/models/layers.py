@@ -369,8 +369,11 @@ def attention_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig, 
     q = apply_rope(q, posq, cfg.rope_theta)
     k_new = apply_rope(k_new, posq, cfg.rope_theta)
     slot = jnp.mod(cur, smax)
-    ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-    cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
+    with jax.named_scope("kv_write"):
+        ck = jax.lax.dynamic_update_slice_in_dim(
+            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
+        cv = jax.lax.dynamic_update_slice_in_dim(
+            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
     n_valid = jnp.minimum(cur + 1, smax)
     # decode scores over the whole buffer; invalid slots masked via n_valid.
     # window masking is implicit: the swa buffer is only `window` wide.
@@ -406,11 +409,14 @@ def mla_decode(params: dict, x: jax.Array, cache: dict,
     ckv_new = rms_norm(x @ params["wkv_a"], params["kv_norm"], cfg.norm_eps)
     kr_new = apply_rope(x @ params["wk_rope"], posq, cfg.rope_theta)
     slot = jnp.mod(cur, smax)
-    ckv = jax.lax.dynamic_update_slice_in_dim(
-        cache["ckv"], ckv_new[:, None].astype(cache["ckv"].dtype)
-        if ckv_new.ndim == 2 else ckv_new.astype(cache["ckv"].dtype), slot, axis=1)
-    krope = jax.lax.dynamic_update_slice_in_dim(
-        cache["k_rope"], kr_new.astype(cache["k_rope"].dtype), slot, axis=1)
+    with jax.named_scope("kv_write"):
+        ckv = jax.lax.dynamic_update_slice_in_dim(
+            cache["ckv"], ckv_new[:, None].astype(cache["ckv"].dtype)
+            if ckv_new.ndim == 2 else ckv_new.astype(cache["ckv"].dtype),
+            slot, axis=1)
+        krope = jax.lax.dynamic_update_slice_in_dim(
+            cache["k_rope"], kr_new.astype(cache["k_rope"].dtype), slot,
+            axis=1)
 
     # absorb wkv_b's K half into q_nope:  q_abs (B,1,H,kv_lora)
     wkv_b = params["wkv_b"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim + m.v_head_dim)

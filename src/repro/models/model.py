@@ -73,32 +73,40 @@ def _apply_ffn(params: dict, x: jax.Array, cfg: ModelConfig,
     aux = jnp.zeros((), jnp.float32)
     if ffn == "none":
         return x, aux
-    h = rms_norm(x, params["ln2"], cfg.norm_eps)
-    if ffn == "dense":
-        from repro.models.layers import swiglu
-        y = swiglu(params["ffn"], h)
-    else:
-        y, aux = moe_mod.moe_ffn(params["moe"], h, cfg)
-    return x + y, aux
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, params["ln2"], cfg.norm_eps)
+        if ffn == "dense":
+            from repro.models.layers import swiglu
+            y = swiglu(params["ffn"], h)
+        else:
+            y, aux = moe_mod.moe_ffn(params["moe"], h, cfg)
+        return x + y, aux
+
+
+def _mixer_scope(mixer: str) -> str:
+    """The named scope of a mixer's ops: ``attn`` for every attention
+    kind, else the mixer's own name (``ssd``, ``rglru``)."""
+    return mixer if mixer in ("ssd", "rglru") else "attn"
 
 
 def block_train(params: dict, x: jax.Array, cfg: ModelConfig, kind: BlockKind,
                 *, enc_out: jax.Array | None = None,
                 bidirectional: bool = False) -> tuple[jax.Array, jax.Array]:
     mixer, _ = kind
-    h = rms_norm(x, params["ln1"], cfg.norm_eps)
-    if mixer in ("attn", "bidir"):
-        y = attention_train(params["attn"], h, cfg,
-                            bidirectional=bidirectional or mixer == "bidir")
-    elif mixer == "swa":
-        y = attention_train(params["attn"], h, cfg, window=cfg.window)
-    elif mixer == "mla":
-        y = mla_train(params["attn"], h, cfg)
-    elif mixer == "ssd":
-        y = ssm.ssd_block_train(params["ssd"], h, cfg)
-    else:
-        y = griffin.rglru_block_train(params["rglru"], h, cfg)
-    x = x + y
+    with jax.named_scope(_mixer_scope(mixer)):
+        h = rms_norm(x, params["ln1"], cfg.norm_eps)
+        if mixer in ("attn", "bidir"):
+            y = attention_train(params["attn"], h, cfg,
+                                bidirectional=bidirectional or mixer == "bidir")
+        elif mixer == "swa":
+            y = attention_train(params["attn"], h, cfg, window=cfg.window)
+        elif mixer == "mla":
+            y = mla_train(params["attn"], h, cfg)
+        elif mixer == "ssd":
+            y = ssm.ssd_block_train(params["ssd"], h, cfg)
+        else:
+            y = griffin.rglru_block_train(params["rglru"], h, cfg)
+        x = x + y
     if enc_out is not None and "cross" in params:
         h = rms_norm(x, params["ln_x"], cfg.norm_eps)
         x = x + attention_train(params["cross"], h, cfg, kv_source=enc_out)
@@ -111,22 +119,24 @@ def block_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
                  kind: BlockKind, *, cross_memory: dict | None = None
                  ) -> tuple[jax.Array, dict]:
     mixer, _ = kind
-    h = rms_norm(x, params["ln1"], cfg.norm_eps)
     new_cache = dict(cache)
-    if mixer in ("attn", "swa"):
-        y, c = attention_decode(params["attn"], h, cache["attn"], cfg,
-                                window=cfg.window if mixer == "swa" else 0)
-        new_cache["attn"] = c
-    elif mixer == "mla":
-        y, c = mla_decode(params["attn"], h, cache["attn"], cfg)
-        new_cache["attn"] = c
-    elif mixer == "ssd":
-        y, c = ssm.ssd_block_decode(params["ssd"], h, cache["ssd"], cfg)
-        new_cache["ssd"] = c
-    else:
-        y, c = griffin.rglru_block_decode(params["rglru"], h, cache["rglru"], cfg)
-        new_cache["rglru"] = c
-    x = x + y
+    with jax.named_scope(_mixer_scope(mixer)):
+        h = rms_norm(x, params["ln1"], cfg.norm_eps)
+        if mixer in ("attn", "swa"):
+            y, c = attention_decode(params["attn"], h, cache["attn"], cfg,
+                                    window=cfg.window if mixer == "swa" else 0)
+            new_cache["attn"] = c
+        elif mixer == "mla":
+            y, c = mla_decode(params["attn"], h, cache["attn"], cfg)
+            new_cache["attn"] = c
+        elif mixer == "ssd":
+            y, c = ssm.ssd_block_decode(params["ssd"], h, cache["ssd"], cfg)
+            new_cache["ssd"] = c
+        else:
+            y, c = griffin.rglru_block_decode(params["rglru"], h,
+                                              cache["rglru"], cfg)
+            new_cache["rglru"] = c
+        x = x + y
     mem = cross_memory if cross_memory is not None else cache.get("cross")
     if mem is not None and "cross" in params:
         h = rms_norm(x, params["ln_x"], cfg.norm_eps)
@@ -272,20 +282,23 @@ def _run_segments_train(params: dict, x: jax.Array, cfg: ModelConfig, *,
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        if repeats == 1:
-            squeezed = jax.tree.map(lambda p: p[0], seg_params)
-            (x, aux_total), _ = body((x, aux_total), squeezed)
-        else:
-            (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), seg_params)
+        with jax.named_scope("layers"):
+            if repeats == 1:
+                squeezed = jax.tree.map(lambda p: p[0], seg_params)
+                (x, aux_total), _ = body((x, aux_total), squeezed)
+            else:
+                (x, aux_total), _ = jax.lax.scan(body, (x, aux_total),
+                                                 seg_params)
     return x, aux_total
 
 
 def embed_inputs(params: dict, batch: dict, cfg: ModelConfig) -> jax.Array:
-    if cfg.input_kind == "embeds":
-        x = batch["embeds"]
-    else:
-        x = params["embed"][batch["inputs"]]
-    return constrain(x.astype(cfg.cdtype), ("batch", "seq", "d_model"))
+    with jax.named_scope("embed"):
+        if cfg.input_kind == "embeds":
+            x = batch["embeds"]
+        else:
+            x = params["embed"][batch["inputs"]]
+        return constrain(x.astype(cfg.cdtype), ("batch", "seq", "d_model"))
 
 
 def _encoder_forward(params: dict, batch: dict, cfg: ModelConfig, *,
@@ -319,8 +332,9 @@ def forward_train(params: dict, batch: dict, cfg: ModelConfig, *,
 
 
 def _logits(params: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
-    w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    return (h @ w.astype(h.dtype)).astype(jnp.float32)
+    with jax.named_scope("logits"):
+        w = params["embed"].T if cfg.tie_embeddings else params["head"]
+        return (h @ w.astype(h.dtype)).astype(jnp.float32)
 
 
 def _ce_chunk(params: dict, h: jax.Array, targets: jax.Array,
@@ -454,12 +468,13 @@ def prefill_forward(params: dict, batch: dict, cfg: ModelConfig, *,
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        if repeats == 1:
-            squeezed = jax.tree.map(lambda p: p[0], seg_params)
-            x, entries = body(x, squeezed)
-            entries = jax.tree.map(lambda p: p[None], entries)
-        else:
-            x, entries = jax.lax.scan(body, x, seg_params)
+        with jax.named_scope("layers"):
+            if repeats == 1:
+                squeezed = jax.tree.map(lambda p: p[0], seg_params)
+                x, entries = body(x, squeezed)
+                entries = jax.tree.map(lambda p: p[None], entries)
+            else:
+                x, entries = jax.lax.scan(body, x, seg_params)
         segments_cache.append(entries)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, x[:, -1:], cfg)
@@ -504,11 +519,12 @@ def decode_step(params: dict, state: dict, batch: dict, cfg: ModelConfig
                 ) -> tuple[jax.Array, dict]:
     """One-token decode.  batch: {"inputs": (B,1) ids} or {"embeds": (B,1,d)};
     optional {"cross_memory": [...]} for enc-dec.  Returns (logits, state)."""
-    if cfg.input_kind == "embeds" and "embeds" in batch:
-        x = batch["embeds"].astype(cfg.cdtype)
-    else:
-        x = params["embed"][batch["inputs"]].astype(cfg.cdtype)
-    x = constrain(x, ("batch", "seq_res", "d_model"))
+    with jax.named_scope("embed"):
+        if cfg.input_kind == "embeds" and "embeds" in batch:
+            x = batch["embeds"].astype(cfg.cdtype)
+        else:
+            x = params["embed"][batch["inputs"]].astype(cfg.cdtype)
+        x = constrain(x, ("batch", "seq_res", "d_model"))
     cross_mem = batch.get("cross_memory")
 
     new_segments = []
@@ -523,13 +539,14 @@ def decode_step(params: dict, state: dict, batch: dict, cfg: ModelConfig
                 new_cache[str(u)] = c
             return h, new_cache
 
-        if repeats == 1:
-            sp = jax.tree.map(lambda p: p[0], seg_params)
-            sc = jax.tree.map(lambda p: p[0], seg_cache)
-            x, nc = body(x, (sp, sc))
-            nc = jax.tree.map(lambda p: p[None], nc)
-        else:
-            x, nc = jax.lax.scan(body, x, (seg_params, seg_cache))
+        with jax.named_scope("layers"):
+            if repeats == 1:
+                sp = jax.tree.map(lambda p: p[0], seg_params)
+                sc = jax.tree.map(lambda p: p[0], seg_cache)
+                x, nc = body(x, (sp, sc))
+                nc = jax.tree.map(lambda p: p[None], nc)
+            else:
+                x, nc = jax.lax.scan(body, x, (seg_params, seg_cache))
         new_segments.append(nc)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -28,6 +28,7 @@ from __future__ import annotations
 from typing import Any
 
 from repro.core.failures import HardwareShutdownError
+from repro.core.monitoring import SpanLog
 from repro.serve.queue import ServeRequest
 
 
@@ -94,7 +95,12 @@ def advance_slots(slots: ReplicaSlots, next_tokens: list[int]) -> list[ServeRequ
 
 
 class DecodeBackend:
-    """Decode executor protocol shared by the real and simulated planes."""
+    """Decode executor protocol shared by the real and simulated planes.
+
+    The serving driver sets ``span_log`` to its monitor's
+    :class:`~repro.core.monitoring.SpanLog`, which times the steps of a
+    backend that records spans.
+    """
 
     name = "base"
 
@@ -147,6 +153,8 @@ class JaxDecodeBackend(DecodeBackend):
         # pinned to (max_batch, 1), so slot churn never recompiles
         self._decode = jax.jit(lambda p, c, b: decode_step(p, c, b, cfg))
         self._caches: dict[str, Any] = {}
+        # disabled until a serving driver binds its monitor's log
+        self.span_log = SpanLog()
         # a restored replica keeps the device it was first given
         self._placement: dict[str, Any] = {}
 
@@ -187,16 +195,26 @@ class JaxDecodeBackend(DecodeBackend):
             raise HardwareShutdownError(
                 f"replica {replica.name} has no decode state",
                 node=replica.name)
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        for i, tok in enumerate(inputs):
-            if tok is not None:
-                toks[i, 0] = tok
-        dev = self._placement[replica.name]
-        logits, cache = self._decode(self._params[dev], cache,
-                                     {"inputs": jax.device_put(toks, dev)})
-        self._caches[replica.name] = cache
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        return [int(nxt[i]) for i in range(self.max_batch)]
+        spans = self.span_log
+        with spans.span("serve.step", replica=replica.name):
+            with spans.span("serve.step.pack"):
+                toks = np.zeros((self.max_batch, 1), np.int32)
+                for i, tok in enumerate(inputs):
+                    if tok is not None:
+                        toks[i, 0] = tok
+            dev = self._placement[replica.name]
+            with spans.span("serve.step.put"):
+                batch = {"inputs": jax.device_put(toks, dev)}
+            with spans.span("serve.step.launch"):
+                logits, cache = self._decode(self._params[dev], cache, batch)
+            self._caches[replica.name] = cache
+            with spans.span("serve.step.sample"):
+                nxt = jnp.argmax(logits[:, -1], axis=-1)
+            # the host waits here for the device to run the step
+            with spans.span("serve.step.wait"):
+                nxt = np.asarray(nxt)
+            with spans.span("serve.step.unpack"):
+                return [int(nxt[i]) for i in range(self.max_batch)]
 
 
 class SimDecodeBackend(DecodeBackend):

@@ -47,6 +47,7 @@ DataFlowKernel's copy machinery and are inert here.
 from __future__ import annotations
 
 import dataclasses
+import time
 
 from repro.core import MonitoringDatabase
 from repro.core.failures import FailureReport, HardwareShutdownError
@@ -168,6 +169,8 @@ class WrathServeDriver:
         else:
             self.backend = JaxDecodeBackend(cfg, max_batch=max_batch,
                                             seed=seed, max_len=max_len)
+        # the backend's decode-step spans go to this plane's span log
+        self.backend.span_log = self.monitor.spans
         # -- continuous plane state ------------------------------------
         self.queue = RequestQueue(clock=self.clock, capacity=queue_capacity,
                                   monitor=self.monitor)
@@ -460,17 +463,18 @@ class WrathServeDriver:
         happens here — a rejected request never holds a queue position,
         a batch slot, or a decode step.
         """
-        self._ensure_loop()
-        self._requests.append(req)
-        rec = new_task_record(
-            TaskDef(lambda: None, "serve_request", ResourceSpec(), 2),
-            (), {}, default_retries=2)
-        req._rec = rec
-        ok = self.queue.push(req, stack=self.policies, ctx=self._ctx())
-        if ok:
-            self.policies.on_submit(rec, self._ctx())
-            self.events.call_soon(self._pump, name="pump")
-        return ok
+        with self.monitor.spans.span("serve.plane.submit", rid=req.rid):
+            self._ensure_loop()
+            self._requests.append(req)
+            rec = new_task_record(
+                TaskDef(lambda: None, "serve_request", ResourceSpec(), 2),
+                (), {}, default_retries=2)
+            req._rec = rec
+            ok = self.queue.push(req, stack=self.policies, ctx=self._ctx())
+            if ok:
+                self.policies.on_submit(rec, self._ctx())
+                self.events.call_soon(self._pump, name="pump")
+            return ok
 
     def _pump(self) -> None:
         """Refill free slots from the queue (the continuous-batching core).
@@ -479,29 +483,38 @@ class WrathServeDriver:
         request finished, a replica joined, a request arrived.  Each
         pulled request is placed by the scheduler among replicas that
         currently have a free slot and joins that replica's in-flight
-        batch at its next step boundary.
+        batch at its next step boundary.  A seated request's time in the
+        queue is recorded as a ``serve.queue_wait`` span.
         """
-        while True:
-            candidates = [n for n in self.live_replicas()
-                          if self._slots[n.name].free_count() > 0]
-            if not candidates:
-                return
-            free = sum(self._slots[n.name].free_count() for n in candidates)
-            batch = self.queue.pop_ready(free)
-            if not batch:
-                return
-            for req in batch:
+        spans = self.monitor.spans
+        with spans.span("serve.plane.pump"):
+            while True:
                 candidates = [n for n in self.live_replicas()
                               if self._slots[n.name].free_count() > 0]
-                if not candidates:  # pragma: no cover - free counted above
-                    self.queue.push(req, front=True)
+                if not candidates:
                     return
-                node = self.scheduler.select(
-                    req._rec, candidates, pool=self.cluster.pools["serve"])
-                if node is None:
-                    node = candidates[0]
-                self._slots[node.name].admit(req)
-                self._schedule_step(node)
+                free = sum(self._slots[n.name].free_count()
+                           for n in candidates)
+                batch = self.queue.pop_ready(free)
+                if not batch:
+                    return
+                for req in batch:
+                    candidates = [n for n in self.live_replicas()
+                                  if self._slots[n.name].free_count() > 0]
+                    if not candidates:  # pragma: no cover - free counted above
+                        self.queue.push(req, front=True)
+                        return
+                    node = self.scheduler.select(
+                        req._rec, candidates, pool=self.cluster.pools["serve"])
+                    if node is None:
+                        node = candidates[0]
+                    self._slots[node.name].admit(req)
+                    if req.queued_pc is not None:
+                        spans.record("serve.queue_wait", req.queued_pc,
+                                     time.perf_counter(), rid=req.rid,
+                                     replica=node.name)
+                        req.queued_pc = None
+                    self._schedule_step(node)
 
     def _schedule_step(self, node: Node) -> None:
         """Arm the next decode step for ``node`` (one in flight at most)."""
@@ -522,48 +535,53 @@ class WrathServeDriver:
     def _step(self, name: str) -> None:
         """One decode step on one replica: the padded program ticks, every
         occupant advances one token, finished occupants vacate."""
-        self._step_scheduled.discard(name)
-        node = self.cluster.find_node(name)
-        slots = self._slots.get(name)
-        if node is None or slots is None:
-            return
-        occ = slots.occupants()
-        if not occ:
-            return
-        inputs = [r.feed[r.pos] if r is not None else None
-                  for r in slots.slots]
-        t0 = self.clock.now()
-        try:
-            nxt = self.backend.step(node, inputs)
-        except HardwareShutdownError as err:
-            self._on_replica_loss(node, slots, err)
-            self._pump()
-            return
-        cost = self.backend.step_cost_s(node)
-        duration = cost if cost is not None else (self.clock.now() - t0)
-        self.decode_steps += 1
-        # the streaming decode_step profile drives admission's p95 estimate
-        self.monitor.record_task_placement("decode_step", name, "serve",
-                                           ok=True, duration=duration)
-        finished = advance_slots(slots, nxt)
-        now = self.clock.now()
-        for req in occ:
-            if req.generated and not req.first_token_t:
-                req.first_token_t = now
-        for req in finished:
-            req.status = "done"
-            req.finish_t = now
-            if req._rec is not None:
-                req._rec.record_attempt(node=name, pool="serve", worker="-",
-                                        ok=True, error=None,
-                                        duration=req.latency_s,
-                                        now=self.clock.time())
-            self.monitor.record_system_event(
-                "request_done", rid=req.rid, node=name,
-                latency_s=round(req.latency_s, 6))
-        if finished:
-            self._pump()
-        self._schedule_step(node)
+        spans = self.monitor.spans
+        with spans.span("serve.plane.step", replica=name,
+                        step=self.decode_steps):
+            self._step_scheduled.discard(name)
+            node = self.cluster.find_node(name)
+            slots = self._slots.get(name)
+            if node is None or slots is None:
+                return
+            occ = slots.occupants()
+            if not occ:
+                return
+            inputs = [r.feed[r.pos] if r is not None else None
+                      for r in slots.slots]
+            t0 = self.clock.now()
+            try:
+                nxt = self.backend.step(node, inputs)
+            except HardwareShutdownError as err:
+                self._on_replica_loss(node, slots, err)
+                self._pump()
+                return
+            with spans.span("serve.plane.advance"):
+                cost = self.backend.step_cost_s(node)
+                duration = cost if cost is not None else (self.clock.now() - t0)
+                self.decode_steps += 1
+                # the streaming decode_step profile drives admission's p95
+                # estimate
+                self.monitor.record_task_placement("decode_step", name, "serve",
+                                                   ok=True, duration=duration)
+                finished = advance_slots(slots, nxt)
+                now = self.clock.now()
+                for req in occ:
+                    if req.generated and not req.first_token_t:
+                        req.first_token_t = now
+                for req in finished:
+                    req.status = "done"
+                    req.finish_t = now
+                    if req._rec is not None:
+                        req._rec.record_attempt(node=name, pool="serve",
+                                                worker="-", ok=True, error=None,
+                                                duration=req.latency_s,
+                                                now=self.clock.time())
+                    self.monitor.record_system_event(
+                        "request_done", rid=req.rid, node=name,
+                        latency_s=round(req.latency_s, 6))
+            if finished:
+                self._pump()
+            self._schedule_step(node)
 
     def _on_replica_loss(self, node: Node, slots: ReplicaSlots,
                          err: HardwareShutdownError) -> None:
@@ -602,14 +620,10 @@ class WrathServeDriver:
             self.queue.push(req, front=True)
 
     def _tick(self) -> None:
-        """Periodic policy tick: sample serve gauges, run ``on_tick``."""
-        slots_total = self.total_slots()
-        occupied = sum(len(self._slots[n.name].occupants())
-                       for n in self.live_replicas())
-        self.monitor.record_gauge("serve.queue_depth", self.queue.depth())
-        self.monitor.record_gauge("serve.slot_occupancy",
-                                  occupied / max(slots_total, 1))
-        self.policies.on_tick(self._ctx())
+        """Periodic policy tick: sample the queue depth, run ``on_tick``."""
+        with self.monitor.spans.span("serve.plane.tick"):
+            self.monitor.record_gauge("serve.queue_depth", self.queue.depth())
+            self.policies.on_tick(self._ctx())
 
     def inject_fault(self, kind: str, name: str) -> None:
         """Chaos hook: ``kill`` or ``restore`` a replica by name."""

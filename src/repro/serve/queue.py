@@ -19,6 +19,7 @@ analog of the paper's "immediate termination to avoid wasted compute".
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
@@ -55,6 +56,9 @@ class ServeRequest:
     finish_t: float = 0.0
     #: replica failovers this request survived
     recoveries: int = 0
+    #: ``time.perf_counter()`` when it last joined the queue, while the
+    #: monitor's span log is on (the ``serve.queue_wait`` span's start)
+    queued_pc: float | None = field(default=None, repr=False)
     # -- batcher slot state (owned by repro.serve.batcher) ---------------
     feed: list[int] = field(default_factory=list, repr=False)
     pos: int = 0
@@ -171,6 +175,8 @@ class RequestQueue:
                         depth=len(self._items),
                         deadline_s=req.deadline_s)
         req.status = "queued"
+        if self.monitor is not None and self.monitor.spans.enabled:
+            req.queued_pc = time.perf_counter()
         if front:
             self._items.appendleft(req)
         else:

@@ -7,6 +7,8 @@ serve and train programs and both Pallas kernels at published widths
 for one v5e chip and check that each fits its 16 GB.  Nothing runs.
 """
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +78,64 @@ def test_granite_decode_step_full_width(one_chip):
     compiled = jax.jit(lambda p, c, b: decode_step(p, c, b, cfg)).lower(
         params, cache, batch).compile()
     _fits(compiled)
+
+
+@pytest.fixture(scope="module")
+def granite_decode_text(one_chip):
+    """The granite-3-2b decode step compiled for one v5e chip: its HLO
+    text, the parameters that hold the cache, and the abstract cache, at
+    the benchmark's 96 slots of 512 positions."""
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench import scope_reduce
+
+    cfg = get_config("granite-3-2b")
+    params = _on(one_chip, abstract(param_defs(cfg)))
+    cache = _on(one_chip, abstract(cache_defs(cfg, 96, 512)))
+    batch = _on(one_chip, {"inputs": jax.ShapeDtypeStruct((96, 1), jnp.int32)})
+    text, state = scope_reduce.program_text(
+        jax.jit(lambda p, c, b: decode_step(p, c, b, cfg)), params, cache,
+        batch, state_arg=1)
+    return text, state, cache
+
+
+def test_granite_decode_step_keeps_the_model_scopes(granite_decode_text):
+    # the scopes reach the TPU program's op metadata, where a profiler
+    # trace's op names can be mapped back to them
+    import re
+
+    text, _, _ = granite_decode_text
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/layers/" in n and
+               n.endswith("/attn/kv_write/dynamic_update_slice")
+               for n in op_names)
+    for scope in ("/embed/", "/layers/while/body/dynamic_slice",
+                  "/attn/", "/ffn/", "/logits/"):
+        assert any(scope in n for n in op_names), scope
+
+
+def test_granite_decode_layer_slices_split_into_state_and_weights(
+        granite_decode_text):
+    # the layer scan's own slices and write-backs are told apart by the
+    # data they move: a weight's layer slice, or the cache's
+    import math
+    import re
+
+    from bench import scope_reduce
+
+    text, state, cache = granite_decode_text
+    scopes = scope_reduce.hlo_scopes(text, state)
+    sizes = {}
+    for m in re.finditer(r"^\s*(?:ROOT\s+)?%?([^\s=]+) = \w+\[([\d,]*)\]",
+                         text, re.M):
+        sizes[m.group(1)] = math.prod(int(d) for d in m.group(2).split(",") if d)
+    per_layer = {math.prod(leaf.shape[1:]) for leaf in jax.tree.leaves(cache)}
+    state_ops = [n for n, c in scopes.items() if c == "layer_state"]
+    weight_ops = [n for n, c in scopes.items() if c == "layer_weights"]
+    assert state_ops and weight_ops
+    # granite's per-layer cache slices are [96, 512, 8, 64]; no weight
+    # slice has as many elements
+    assert any(sizes[n] in per_layer for n in state_ops)
+    assert not any(sizes[n] in per_layer for n in weight_ops)
 
 
 def test_supervisor_grad_fn_granite_4_layers(one_chip, tmp_path):

@@ -41,6 +41,7 @@ SYSTEM_EVENTS = frozenset({
     'node_undrain',
     'policy_error',
     'replica_lost',
+    'replica_restarted',
     'request_admitted',
     'request_done',
     'request_rejected',

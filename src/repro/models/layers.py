@@ -278,7 +278,8 @@ def attention_train(params: dict, x: jax.Array, cfg: ModelConfig, *,
     """Self- (or cross-) attention over a full sequence.
 
     kv_source: if given (encoder output), cross-attention without RoPE.
-    return_kv: also return the (roped) K/V for prefill cache capture.
+    return_kv: also return the (roped) K/V for prefill cache capture,
+    each (B, S, KV * hd) as the decode state holds them.
     """
     b, s, _ = x.shape
     hd, h, kv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -291,7 +292,8 @@ def attention_train(params: dict, x: jax.Array, cfg: ModelConfig, *,
         pos = positions if positions is not None else jnp.arange(s)
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos if sk == s else jnp.arange(sk), cfg.rope_theta)
-    kv_for_cache = {"k": k, "v": v}
+    # the decode state's layout: KV heads folded into one minor axis
+    kv_for_cache = {"k": k.reshape(b, sk, kv * hd), "v": v.reshape(b, sk, kv * hd)}
     q, k, v, h_orig = _pad_heads(q, k, v, cfg)
     q = constrain(q, ("batch", "seq", "heads", None))
     k = constrain(k, ("batch", "seq", "heads" if cfg.head_pad else "kv_heads",
@@ -340,65 +342,122 @@ def mla_train(params: dict, x: jax.Array, cfg: ModelConfig, *,
 
 
 # ---------------------------------------------------------------------------
-# decode (single new token against a ring-buffer cache)
+# decode (single new token, written in place into a stacked ring buffer)
 # ---------------------------------------------------------------------------
 
 
-def attention_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig, *,
-                     window: int = 0,
-                     cross_memory: dict | None = None) -> tuple[jax.Array, dict]:
-    """x: (B, 1, d).  cache: {"k","v": (B, Smax, KV, hd), "len": (B,) or ()}.
+def layer_slice(buf: jax.Array, layer: jax.Array | int) -> jax.Array:
+    """Layer ``layer`` of a segment's stacked state ``(L, ...)``."""
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
 
-    Ring-buffer semantics: the new KV overwrites position ``len % Smax``.
-    Cross-attention (enc-dec) passes ``cross_memory`` = {"k","v"} instead;
-    the cache is untouched.
+
+#: positions a write moves at least: one tile of a bf16 buffer's second
+#: minor axis on the TPU, so that a written block is whole tiles
+WRITE_BLOCK = 16
+
+
+def write_position(buf: jax.Array, new: jax.Array, layer: jax.Array | int,
+                   pos: jax.Array) -> jax.Array:
+    """``buf`` (L, B, S, ...) with ``new`` (B, 1, ...) written at ``layer``,
+    position ``pos``, in place where ``buf`` is the step's own (donated or
+    loop-carried) state.
+
+    The aligned block of positions that holds ``pos`` is read, the one
+    position replaced, and the block written back.  A write of the one
+    position alone is a block one row high, which the compiler pads to a
+    whole tile; it then lays the whole buffer out with the positions
+    major to suit the write, and copies every layer's state between that
+    layout and the one its reads want, each step.  The block's start is
+    masked to a multiple of the block and passed as non-negative, so the
+    compiler knows it is aligned and writes whole tiles.
+    """
+    s = buf.shape[2]
+    blk = math.gcd(s, WRITE_BLOCK)
+    base = jnp.bitwise_and(pos, -blk)
+    start = (layer, 0, base) + (0,) * (buf.ndim - 3)
+    old = jax.lax.dynamic_slice(buf, start, (1, buf.shape[1], blk) + buf.shape[3:],
+                                allow_negative_indices=False)
+    here = (jnp.arange(blk) == pos - base).reshape((1, 1, blk) + (1,) * (buf.ndim - 3))
+    return jax.lax.dynamic_update_slice(
+        buf, jnp.where(here, new[None].astype(buf.dtype), old), start,
+        allow_negative_indices=False)
+
+
+def _attend(q: jax.Array, k: jax.Array, v: jax.Array,
+            n_valid: jax.Array | int) -> jax.Array:
+    """One token's attention against one layer's folded K/V.
+
+    q: (B, H, hd); k, v: (B, S, KV * hd), the KV heads side by side on
+    the minor axis; positions from ``n_valid`` on are masked.  Returns
+    (B, H * hd).  Each query head is laid on its own KV head's lanes of a
+    zero row, so both products read the buffer as it lies, with no copy
+    into a per-head layout; the products with the zeros add nothing."""
+    b, h, hd = q.shape
+    smax, kvh = k.shape[1], k.shape[2] // hd
+    g = h // kvh
+    heads = jnp.eye(kvh, dtype=q.dtype)
+    rows = jnp.einsum("bkgd,kj->bkgjd", q.reshape(b, kvh, g, hd),
+                      heads).reshape(b, h, kvh * hd)
+    scores = jnp.einsum("bhe,bse->bhs", rows, k,
+                        preferred_element_type=jnp.float32) / math.sqrt(hd)
+    scores = jnp.where(jnp.arange(smax) < n_valid, scores, -1e30)
+    p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhs,bse->bhe", p, v).reshape(b, kvh, g, kvh, hd)
+    return jnp.einsum("bkgjd,kj->bkgd", out, heads).reshape(b, h * hd)
+
+
+def attention_decode(params: dict, x: jax.Array, cache: dict,
+                     layer: jax.Array | int, cfg: ModelConfig, *,
+                     cross: bool = False) -> tuple[jax.Array, dict]:
+    """x: (B, 1, d).  cache: the segment's stacked state {"k","v": (L, B,
+    Smax, KV * hd), "len": (L,)}; ``layer`` indexes it.
+
+    Ring-buffer semantics: the new KV overwrites position ``len % Smax``
+    of ``layer``, and nothing else is written; a sliding-window layer's
+    buffer is only the window wide.  Cross-attention (enc-dec,
+    ``cross``) reads the layer's encoder K/V ({"k","v"}) and writes
+    nothing.
     """
     b = x.shape[0]
     hd, h, kvh = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     q = (x @ params["wq"]).reshape(b, 1, h, hd)
-    if cross_memory is not None:
-        k, v = cross_memory["k"], cross_memory["v"]
-        out = mha(q, k, v, causal=False)
-        return out.reshape(b, 1, h * hd) @ params["wo"], cache
+    if cross:
+        k, v = layer_slice(cache["k"], layer), layer_slice(cache["v"], layer)
+        out = _attend(q[:, 0], k, v, k.shape[1])
+        return out[:, None] @ params["wo"], cache
 
-    smax = cache["k"].shape[1]
-    cur = cache["len"]                                  # scalar int32
+    smax = cache["k"].shape[2]
+    cur = layer_slice(cache["len"], layer)              # scalar int32
     k_new = (x @ params["wk"]).reshape(b, 1, kvh, hd)
-    v_new = (x @ params["wv"]).reshape(b, 1, kvh, hd)
+    v_new = (x @ params["wv"]).reshape(b, 1, kvh * hd)
     posq = jnp.full((1,), cur, dtype=jnp.int32)
     q = apply_rope(q, posq, cfg.rope_theta)
-    k_new = apply_rope(k_new, posq, cfg.rope_theta)
+    k_new = apply_rope(k_new, posq, cfg.rope_theta).reshape(b, 1, kvh * hd)
     slot = jnp.mod(cur, smax)
     with jax.named_scope("kv_write"):
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
-    n_valid = jnp.minimum(cur + 1, smax)
-    # decode scores over the whole buffer; invalid slots masked via n_valid.
-    # window masking is implicit: the swa buffer is only `window` wide.
-    g = h // kvh
-    qh = q.reshape(b, 1, kvh, g, hd)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qh, ck,
-                        preferred_element_type=jnp.float32) / math.sqrt(hd)
-    kpos = jnp.arange(smax)[None, :]
-    mask = kpos < n_valid
-    scores = jnp.where(mask[None, None, None], scores, -1e30)
-    p = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", p, cv).reshape(b, 1, h * hd)
-    new_cache = {"k": ck, "v": cv, "len": cur + 1}
-    return out @ params["wo"], new_cache
+        ck = write_position(cache["k"], k_new, layer, slot)
+        cv = write_position(cache["v"], v_new, layer, slot)
+    # decode scores over the whole buffer; invalid slots masked via n_valid
+    out = _attend(q[:, 0], layer_slice(ck, layer), layer_slice(cv, layer),
+                  jnp.minimum(cur + 1, smax))
+    new_cache = {"k": ck, "v": cv, "len": jax.lax.dynamic_update_index_in_dim(
+        cache["len"], cur + 1, layer, 0)}
+    return out[:, None] @ params["wo"], new_cache
 
 
 def mla_decode(params: dict, x: jax.Array, cache: dict,
-               cfg: ModelConfig) -> tuple[jax.Array, dict]:
+               layer: jax.Array | int, cfg: ModelConfig
+               ) -> tuple[jax.Array, dict]:
     """Absorbed MLA decode: scores/outputs computed against the compressed
-    latent cache (c_kv, k_rope) without materializing per-head K/V."""
+    latent cache (c_kv, k_rope) without materializing per-head K/V.
+    cache: the segment's stacked state {"ckv": (L, B, Smax, r), "k_rope":
+    (L, B, Smax, rope), "len": (L,)}; one position of ``layer`` is
+    written."""
     m: MLACfg = cfg.mla  # type: ignore[assignment]
     b = x.shape[0]
     h = cfg.n_heads
-    smax = cache["ckv"].shape[1]
-    cur = cache["len"]
+    smax = cache["ckv"].shape[2]
+    cur = layer_slice(cache["len"], layer)
     posq = jnp.full((1,), cur, dtype=jnp.int32)
 
     cq = rms_norm(x @ params["wq_a"], params["q_norm"], cfg.norm_eps)
@@ -410,13 +469,9 @@ def mla_decode(params: dict, x: jax.Array, cache: dict,
     kr_new = apply_rope(x @ params["wk_rope"], posq, cfg.rope_theta)
     slot = jnp.mod(cur, smax)
     with jax.named_scope("kv_write"):
-        ckv = jax.lax.dynamic_update_slice_in_dim(
-            cache["ckv"], ckv_new[:, None].astype(cache["ckv"].dtype)
-            if ckv_new.ndim == 2 else ckv_new.astype(cache["ckv"].dtype),
-            slot, axis=1)
-        krope = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_rope"], kr_new.astype(cache["k_rope"].dtype), slot,
-            axis=1)
+        ckv_all = write_position(cache["ckv"], ckv_new, layer, slot)
+        krope_all = write_position(cache["k_rope"], kr_new, layer, slot)
+    ckv, krope = layer_slice(ckv_all, layer), layer_slice(krope_all, layer)
 
     # absorb wkv_b's K half into q_nope:  q_abs (B,1,H,kv_lora)
     wkv_b = params["wkv_b"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim + m.v_head_dim)
@@ -434,5 +489,7 @@ def mla_decode(params: dict, x: jax.Array, cache: dict,
     p = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
     ctx = jnp.einsum("bhqs,bsr->bqhr", p, ckv)          # (B,1,H,r)
     out = jnp.einsum("bqhr,rhd->bqhd", ctx, w_v).reshape(b, 1, h * m.v_head_dim)
-    new_cache = {"ckv": ckv, "k_rope": krope, "len": cur + 1}
+    new_cache = {"ckv": ckv_all, "k_rope": krope_all,
+                 "len": jax.lax.dynamic_update_index_in_dim(
+                     cache["len"], cur + 1, layer, 0)}
     return out @ params["wo"], new_cache

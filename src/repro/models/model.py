@@ -27,6 +27,7 @@ from repro.models.layers import (
     attention_decode,
     attention_train,
     constrain,
+    layer_slice,
     make_attention_defs,
     make_ffn_defs,
     make_mla_defs,
@@ -115,35 +116,40 @@ def block_train(params: dict, x: jax.Array, cfg: ModelConfig, kind: BlockKind,
     return x, aux
 
 
-def block_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
-                 kind: BlockKind, *, cross_memory: dict | None = None
+def block_decode(params: dict, x: jax.Array, state: dict,
+                 layer: jax.Array | int, cfg: ModelConfig, kind: BlockKind
                  ) -> tuple[jax.Array, dict]:
+    """One block's decode step against its unit's stacked state (every
+    leaf ``(L, ...)``), of which it updates layer ``layer`` in place:
+    attention writes one position, the recurrent mixers their small state
+    whole.  Returns (x, state)."""
     mixer, _ = kind
-    new_cache = dict(cache)
+    state = dict(state)
     with jax.named_scope(_mixer_scope(mixer)):
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
         if mixer in ("attn", "swa"):
-            y, c = attention_decode(params["attn"], h, cache["attn"], cfg,
-                                    window=cfg.window if mixer == "swa" else 0)
-            new_cache["attn"] = c
+            y, state["attn"] = attention_decode(params["attn"], h,
+                                                state["attn"], layer, cfg)
         elif mixer == "mla":
-            y, c = mla_decode(params["attn"], h, cache["attn"], cfg)
-            new_cache["attn"] = c
-        elif mixer == "ssd":
-            y, c = ssm.ssd_block_decode(params["ssd"], h, cache["ssd"], cfg)
-            new_cache["ssd"] = c
+            y, state["attn"] = mla_decode(params["attn"], h, state["attn"],
+                                          layer, cfg)
         else:
-            y, c = griffin.rglru_block_decode(params["rglru"], h,
-                                              cache["rglru"], cfg)
-            new_cache["rglru"] = c
+            step = (ssm.ssd_block_decode if mixer == "ssd"
+                    else griffin.rglru_block_decode)
+            y, new = step(params[mixer], h,
+                          jax.tree.map(lambda s: layer_slice(s, layer),
+                                       state[mixer]), cfg)
+            state[mixer] = jax.tree.map(
+                lambda s, n: jax.lax.dynamic_update_index_in_dim(
+                    s, n.astype(s.dtype), layer, 0), state[mixer], new)
         x = x + y
-    mem = cross_memory if cross_memory is not None else cache.get("cross")
-    if mem is not None and "cross" in params:
+    if "cross" in state and "cross" in params:
         h = rms_norm(x, params["ln_x"], cfg.norm_eps)
-        y, _ = attention_decode(params["cross"], h, {}, cfg, cross_memory=mem)
+        y, _ = attention_decode(params["cross"], h, state["cross"], layer,
+                                cfg, cross=True)
         x = x + y
     x, _ = _apply_ffn(params, x, cfg, kind)
-    return x, new_cache
+    return x, state
 
 
 # ---------------------------------------------------------------------------
@@ -160,26 +166,26 @@ def _block_cache_defs(cfg: ModelConfig, kind: BlockKind, batch: int,
     cross: dict = {}
     if cfg.encoder_layers:
         cross = {"cross": {
-            "k": pdef((batch, "batch"), (seq_len, "seq"), (kv, "kv_heads"),
-                      (hd, None), init="zeros"),
-            "v": pdef((batch, "batch"), (seq_len, "seq"), (kv, "kv_heads"),
-                      (hd, None), init="zeros"),
+            "k": pdef((batch, "batch"), (seq_len, "seq"), (kv * hd, "kv_heads"),
+                      init="zeros"),
+            "v": pdef((batch, "batch"), (seq_len, "seq"), (kv * hd, "kv_heads"),
+                      init="zeros"),
         }}
     if mixer in ("attn", "bidir"):
         smax = seq_len
         return {"attn": {
-            "k": pdef((batch, "batch"), (smax, "seq"), (kv, "kv_heads"), (hd, None),
+            "k": pdef((batch, "batch"), (smax, "seq"), (kv * hd, "kv_heads"),
                       init="zeros"),
-            "v": pdef((batch, "batch"), (smax, "seq"), (kv, "kv_heads"), (hd, None),
+            "v": pdef((batch, "batch"), (smax, "seq"), (kv * hd, "kv_heads"),
                       init="zeros"),
             "len": pdef(init="zeros", dtype=jnp.int32),
         }, **cross}
     if mixer == "swa":
         smax = min(cfg.window, seq_len)
         return {"attn": {
-            "k": pdef((batch, "batch"), (smax, None), (kv, "kv_heads"), (hd, None),
+            "k": pdef((batch, "batch"), (smax, None), (kv * hd, "kv_heads"),
                       init="zeros"),
-            "v": pdef((batch, "batch"), (smax, None), (kv, "kv_heads"), (hd, None),
+            "v": pdef((batch, "batch"), (smax, None), (kv * hd, "kv_heads"),
                       init="zeros"),
             "len": pdef(init="zeros", dtype=jnp.int32),
         }}
@@ -485,8 +491,6 @@ def prefill_cross_memory(params: dict, cache: dict, enc_out: jax.Array,
                          cfg: ModelConfig) -> dict:
     """Precompute per-decoder-layer cross-attention K/V from the encoder
     output and store them in the decode cache (enc-dec serving prefill)."""
-    hd, kv = cfg.resolved_head_dim, cfg.n_kv_heads
-    b, s, _ = enc_out.shape
     new_segments = []
     for seg_params, seg_cache, (unit, repeats) in zip(
             params["segments"], cache["segments"], cfg.scan_segments()):
@@ -500,10 +504,8 @@ def prefill_cross_memory(params: dict, cache: dict, enc_out: jax.Array,
                 v = jnp.einsum("bsd,rdf->rbsf", enc_out,
                                cross_p["wv"].astype(enc_out.dtype))
                 entry["cross"] = {
-                    "k": k.reshape(repeats, b, s, kv, hd).astype(
-                        entry["cross"]["k"].dtype),
-                    "v": v.reshape(repeats, b, s, kv, hd).astype(
-                        entry["cross"]["v"].dtype),
+                    "k": k.astype(entry["cross"]["k"].dtype),
+                    "v": v.astype(entry["cross"]["v"].dtype),
                 }
             seg_new[str(u)] = entry
         new_segments.append(seg_new)
@@ -517,37 +519,42 @@ def prefill_cross_memory(params: dict, cache: dict, enc_out: jax.Array,
 
 def decode_step(params: dict, state: dict, batch: dict, cfg: ModelConfig
                 ) -> tuple[jax.Array, dict]:
-    """One-token decode.  batch: {"inputs": (B,1) ids} or {"embeds": (B,1,d)};
-    optional {"cross_memory": [...]} for enc-dec.  Returns (logits, state)."""
+    """One-token decode.  batch: {"inputs": (B,1) ids} or {"embeds": (B,1,d)}.
+    Returns (logits, state).
+
+    The layer scan carries each segment's stacked state and slices only
+    the weights, so a layer updates its own part of the state where it
+    lies (one position per attention layer) and no layer's state is
+    sliced out or stacked back; jitted with the state donated, the step
+    writes into the caller's buffers."""
     with jax.named_scope("embed"):
         if cfg.input_kind == "embeds" and "embeds" in batch:
             x = batch["embeds"].astype(cfg.cdtype)
         else:
             x = params["embed"][batch["inputs"]].astype(cfg.cdtype)
         x = constrain(x, ("batch", "seq_res", "d_model"))
-    cross_mem = batch.get("cross_memory")
 
     new_segments = []
-    for seg_params, seg_cache, (unit, repeats) in zip(
+    for seg_params, seg_state, (unit, repeats) in zip(
             params["segments"], state["segments"], cfg.scan_segments()):
-        def body(h, xs, _unit=unit):
-            layer_params, layer_cache = xs
-            new_cache = {}
+        def body(carry, xs, _unit=unit):
+            h, st = carry
+            layer, layer_params = xs
+            st = dict(st)
             for u, kind in enumerate(_unit):
-                h, c = block_decode(layer_params[str(u)], h, layer_cache[str(u)],
-                                    cfg, kind, cross_memory=cross_mem)
-                new_cache[str(u)] = c
-            return h, new_cache
+                h, st[str(u)] = block_decode(layer_params[str(u)], h,
+                                             st[str(u)], layer, cfg, kind)
+            return (h, st), None
 
         with jax.named_scope("layers"):
             if repeats == 1:
                 sp = jax.tree.map(lambda p: p[0], seg_params)
-                sc = jax.tree.map(lambda p: p[0], seg_cache)
-                x, nc = body(x, (sp, sc))
-                nc = jax.tree.map(lambda p: p[None], nc)
+                (x, seg_state), _ = body((x, seg_state), (0, sp))
             else:
-                x, nc = jax.lax.scan(body, x, (seg_params, seg_cache))
-        new_segments.append(nc)
+                (x, seg_state), _ = jax.lax.scan(
+                    body, (x, seg_state),
+                    (jnp.arange(repeats, dtype=jnp.int32), seg_params))
+        new_segments.append(seg_state)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, x, cfg)

@@ -12,8 +12,9 @@ Two decode backends share the slot protocol:
 * :class:`JaxDecodeBackend` — the real model: one device-resident KV
   cache per replica sized ``(max_batch, max_len)``, each replica on its
   own chip where the host has several, one jitted
-  ``decode_step`` program reused every step (ring-buffer cache, so the
-  program never recompiles as requests come and go).  A request joining
+  ``decode_step`` program reused every step, the cache donated to it and
+  written in place (ring-buffer cache, so the program never recompiles
+  as requests come and go).  A request joining
   mid-flight is teacher-forced through its prompt (plus any tokens
   recovered from a lost replica) inside the shared program — the
   reproduction-scale stand-in for a prefill/generate split.
@@ -27,7 +28,7 @@ from __future__ import annotations
 
 from typing import Any
 
-from repro.core.failures import HardwareShutdownError
+from repro.core.failures import HardwareShutdownError, WorkerLostError
 from repro.core.monitoring import SpanLog
 from repro.serve.queue import ServeRequest
 
@@ -94,6 +95,20 @@ def advance_slots(slots: ReplicaSlots, next_tokens: list[int]) -> list[ServeRequ
     return finished
 
 
+def decode_program(cfg: Any):
+    """The jitted decode step every replica runs: ONE program for every
+    replica and every occupancy, its shapes pinned to (max_batch, 1) so
+    slot churn never recompiles.  The decode state (argument 1) is
+    donated: the step writes its one new position per layer into the
+    caller's buffers, and the caller's handle is deleted."""
+    import jax
+
+    from repro.models import decode_step
+
+    return jax.jit(lambda p, c, b: decode_step(p, c, b, cfg),
+                   donate_argnums=(1,))
+
+
 class DecodeBackend:
     """Decode executor protocol shared by the real and simulated planes.
 
@@ -114,7 +129,9 @@ class DecodeBackend:
         """One decode step: per-slot input token (None = free slot) →
         per-slot next token.  Raises
         :class:`~repro.core.failures.HardwareShutdownError` if the
-        replica's hardware is down."""
+        replica's hardware is down, and
+        :class:`~repro.core.failures.WorkerLostError` if its decode state
+        is gone."""
         raise NotImplementedError
 
     def step_cost_s(self, replica: Any) -> float | None:
@@ -139,7 +156,7 @@ class JaxDecodeBackend(DecodeBackend):
                  max_len: int = 64, devices: list | None = None):
         import jax
 
-        from repro.models import decode_step, materialize, param_defs
+        from repro.models import materialize, param_defs
 
         self.cfg = cfg
         self.max_batch = max_batch
@@ -149,9 +166,7 @@ class JaxDecodeBackend(DecodeBackend):
             materialize(param_defs(cfg), jax.random.PRNGKey(seed)),
             self.devices[0])
         self._params = {self.devices[0]: self.params}
-        # ONE program for every replica and every occupancy: shapes are
-        # pinned to (max_batch, 1), so slot churn never recompiles
-        self._decode = jax.jit(lambda p, c, b: decode_step(p, c, b, cfg))
+        self._decode = decode_program(cfg)
         self._caches: dict[str, Any] = {}
         # disabled until a serving driver binds its monitor's log
         self.span_log = SpanLog()
@@ -194,6 +209,12 @@ class JaxDecodeBackend(DecodeBackend):
         if cache is None:  # pragma: no cover - start_replica guards this
             raise HardwareShutdownError(
                 f"replica {replica.name} has no decode state",
+                node=replica.name)
+        if any(leaf.is_deleted() for leaf in jax.tree.leaves(cache)):
+            # the state was donated to an earlier step (or freed) and is
+            # gone: the replica's decode worker is lost, its chip is not
+            raise WorkerLostError(
+                f"replica {replica.name} lost its decode state",
                 node=replica.name)
         spans = self.span_log
         with spans.span("serve.step", replica=replica.name):

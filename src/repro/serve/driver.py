@@ -50,7 +50,8 @@ import dataclasses
 import time
 
 from repro.core import MonitoringDatabase
-from repro.core.failures import FailureReport, HardwareShutdownError
+from repro.core.failures import (FailureReport, HardwareShutdownError,
+                                 WorkerLostError)
 from repro.engine.cluster import Cluster, Node, ResourcePool
 from repro.engine.events import REAL_CLOCK, Clock, EventLoop
 from repro.engine.policies import PolicyStack, WrathPolicy, normalize_policies
@@ -551,7 +552,7 @@ class WrathServeDriver:
             t0 = self.clock.now()
             try:
                 nxt = self.backend.step(node, inputs)
-            except HardwareShutdownError as err:
+            except (HardwareShutdownError, WorkerLostError) as err:
                 self._on_replica_loss(node, slots, err)
                 self._pump()
                 return
@@ -584,14 +585,24 @@ class WrathServeDriver:
             self._schedule_step(node)
 
     def _on_replica_loss(self, node: Node, slots: ReplicaSlots,
-                         err: HardwareShutdownError) -> None:
+                         err: HardwareShutdownError | WorkerLostError) -> None:
         """Failover: evict occupants, consult the policy stack per request,
-        requeue survivors at the head (they already waited their turn)."""
+        requeue survivors at the head (they already waited their turn).
+
+        A replica that lost only its decode state (``WorkerLostError``, its
+        hardware up) is restarted on a fresh state first: requests the
+        policies retry can replay there, and the plane keeps serving on
+        it, where it would otherwise hold no live replica."""
         evicted = slots.evict_all()
         self._slots.pop(node.name, None)
         self.backend.drop_replica(node.name)
         self.monitor.record_system_event("replica_lost", node=node.name,
                                          in_flight=len(evicted))
+        if isinstance(err, WorkerLostError) and node.healthy:
+            self.backend.start_replica(node)
+            self._slots[node.name] = ReplicaSlots(self.max_batch)
+            self.monitor.record_system_event("replica_restarted",
+                                             node=node.name)
         now = self.clock.now()
         for req in evicted:
             rec = req._rec

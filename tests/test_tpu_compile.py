@@ -7,6 +7,7 @@ serve and train programs and both Pallas kernels at published widths
 for one v5e chip and check that each fits its 16 GB.  Nothing runs.
 """
 import os
+import re
 import sys
 from pathlib import Path
 
@@ -19,7 +20,9 @@ from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention_bh
 from repro.kernels.ssd_scan import ssd_scan_kernel
 from repro.models import abstract, cache_defs, decode_step, param_defs
+from repro.models.layers import WRITE_BLOCK
 from repro.optim import OptConfig
+from repro.serve.batcher import decode_program
 from repro.train import WrathTrainSupervisor
 
 V5E_HBM_BYTES = 16e9
@@ -80,29 +83,33 @@ def test_granite_decode_step_full_width(one_chip):
     _fits(compiled)
 
 
-@pytest.fixture(scope="module")
-def granite_decode_text(one_chip):
-    """The granite-3-2b decode step compiled for one v5e chip: its HLO
-    text, the parameters that hold the cache, and the abstract cache, at
-    the benchmark's 96 slots of 512 positions."""
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-    from bench import scope_reduce
-
+def _granite_decode_args(one_chip):
+    """granite-3-2b's weights, decode state and inputs at the benchmark's
+    96 slots of 512 positions, on one described v5e chip."""
     cfg = get_config("granite-3-2b")
     params = _on(one_chip, abstract(param_defs(cfg)))
     cache = _on(one_chip, abstract(cache_defs(cfg, 96, 512)))
     batch = _on(one_chip, {"inputs": jax.ShapeDtypeStruct((96, 1), jnp.int32)})
+    return cfg, params, cache, batch
+
+
+@pytest.fixture(scope="module")
+def granite_decode_text(one_chip):
+    """The granite-3-2b decode step as the serving backend jits it,
+    compiled for one v5e chip: its HLO text, the parameters that hold the
+    cache, and the abstract cache."""
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench import scope_reduce
+
+    cfg, params, cache, batch = _granite_decode_args(one_chip)
     text, state = scope_reduce.program_text(
-        jax.jit(lambda p, c, b: decode_step(p, c, b, cfg)), params, cache,
-        batch, state_arg=1)
+        decode_program(cfg), params, cache, batch, state_arg=1)
     return text, state, cache
 
 
 def test_granite_decode_step_keeps_the_model_scopes(granite_decode_text):
     # the scopes reach the TPU program's op metadata, where a profiler
     # trace's op names can be mapped back to them
-    import re
-
     text, _, _ = granite_decode_text
     op_names = re.findall(r'op_name="([^"]*)"', text)
     assert any("/layers/" in n and
@@ -115,10 +122,11 @@ def test_granite_decode_step_keeps_the_model_scopes(granite_decode_text):
 
 def test_granite_decode_layer_slices_split_into_state_and_weights(
         granite_decode_text):
-    # the layer scan's own slices and write-backs are told apart by the
-    # data they move: a weight's layer slice, or the cache's
+    # the layer scan's own slices are told apart by the data they move: a
+    # weight's layer slice, or the cache's.  The scan carries the cache
+    # and each layer writes its one position in place, so every slice of
+    # the scan's own is a weight's
     import math
-    import re
 
     from bench import scope_reduce
 
@@ -131,11 +139,103 @@ def test_granite_decode_layer_slices_split_into_state_and_weights(
     per_layer = {math.prod(leaf.shape[1:]) for leaf in jax.tree.leaves(cache)}
     state_ops = [n for n, c in scopes.items() if c == "layer_state"]
     weight_ops = [n for n, c in scopes.items() if c == "layer_weights"]
-    assert state_ops and weight_ops
-    # granite's per-layer cache slices are [96, 512, 8, 64]; no weight
+    assert weight_ops and not state_ops
+    # granite's per-layer cache slices are [96, 512, 512]; no weight
     # slice has as many elements
-    assert any(sizes[n] in per_layer for n in state_ops)
     assert not any(sizes[n] in per_layer for n in weight_ops)
+
+
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+#: ops that only route a value, or run the loop that carries it
+_ROUTING = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+def _instructions(text: str):
+    """(computation, name, the dims of each value it makes, opcode,
+    operand names, computations it calls, each value's layout from minor
+    to major) of every instruction of a compiled HLO module's text."""
+    comp = None
+    for line in text.splitlines():
+        if not line.startswith(" ") and line.rstrip().endswith("{"):
+            words = line.split()
+            comp = (words[1] if words[0] == "ENTRY" else words[0]).lstrip("%")
+            continue
+        head, eq, rest = line.partition(" = ")
+        op = _OPCODE.search(" " + rest) if eq and comp is not None else None
+        if op is None:
+            continue
+        shape = (" " + rest)[:op.start()]
+        args = (" " + rest)[op.end():].split(")", 1)[0]
+        made = re.findall(r"\w+\[([\d,]*)\](?:\{([\d,]*))?", shape)
+        yield (comp, head.split()[-1].lstrip("%"),
+               [tuple(int(d) for d in m.split(",") if d) for m, _ in made],
+               op.group(1), re.findall(r"%([\w.\-]+)", args),
+               re.findall(r"\bcalls=%?([\w.\-]+)", rest),
+               [tuple(int(d) for d in lay.split(",") if d) for _, lay in made])
+
+
+def test_granite_decode_step_updates_its_state_in_place(one_chip):
+    """The decode step as the serving backend runs it, at the benchmark's
+    96 slots of 512 positions, aliases the whole decode state, holds no
+    second copy of it, and writes one position per layer.
+
+    Readings of this compile (the installed TPU compiler, a described
+    v5e), temporaries / aliased bytes:
+
+    * the layer scan slicing the stacked state (``xs``) and stacking it
+      back (``ys``), not donated: 0 / 0, and a fresh 4.05 GB output each
+      step;
+    * the same, donated: 4.03 GB / 4.03 GB, the scan's ``ys`` a second
+      whole state copied into the donated one;
+    * the state carried in the scan and written at one position, donated:
+      5.37 GB / 4.03 GB, the write's fused operand laying the carried
+      state out for itself, four copies of the whole state into that
+      layout and back;
+    * one buffer per layer, the layer loop unrolled, donated: 0.26 GB /
+      4.03 GB, and a cold compile of 24 s against 3.8 s;
+    * the state carried, each one-position write's operand kept out of
+      its fusion (an optimization barrier): 0.0004 GB / 4.03 GB, but the
+      state laid out with the positions minor, so that each write
+      touches every tile of a layer's slab (measured on a v5e: as long as
+      the copy it replaced);
+    * as built, the KV heads folded into the minor axis, positions
+      second, and each write an aligned block of ``WRITE_BLOCK``
+      positions: 0.0004 GB / 4.03 GB.
+    """
+    cfg, params, cache, batch = _granite_decode_args(one_chip)
+    compiled = decode_program(cfg).lower(params, cache, batch).compile()
+    mem = compiled.memory_analysis()
+    leaves = jax.tree.leaves(cache)
+    assert mem.alias_size_in_bytes >= sum(
+        leaf.size * leaf.dtype.itemsize for leaf in leaves)
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    stacked = {leaf.shape for leaf in leaves if leaf.ndim > 1}
+    slabs = {s[1:] for s in stacked} | {(1,) + s[1:] for s in stacked}
+    instrs = list(_instructions(text))
+    dims = {name: made[0] for _, name, made, *_ in instrs if made}
+    fused = {c for _, _, _, _, _, calls, _ in instrs for c in calls}
+    writes_in = {comp for comp, _, made, op, *_ in instrs
+                 if op == "dynamic-update-slice" and stacked & set(made)}
+    writes = 0
+    for comp, name, made, op, operands, calls, layouts in instrs:
+        if stacked & set(made) and op not in _ROUTING:
+            # nothing makes a value of the whole state's shape but a
+            # write of one block of positions into it, alone or fused
+            assert op == "dynamic-update-slice" or (
+                op == "fusion" and writes_in & set(calls)), (name, op)
+            if op == "dynamic-update-slice":
+                block = dims[operands[1]]
+                assert block[2] <= WRITE_BLOCK, (name, block)
+                # the positions are not the minor (lane) axis, so that a
+                # block of them is whole rows of tiles, not a column
+                # through every tile of the layer
+                assert layouts[0][0] != 2, (name, layouts[0])
+                writes += 1
+        # a layer's slab is read inside the fusions that use it, never
+        # made on its own
+        assert comp in fused or not slabs & set(made), (name, op, made)
+    assert writes == 2                         # K and V, in the layer loop
 
 
 def test_supervisor_grad_fn_granite_4_layers(one_chip, tmp_path):

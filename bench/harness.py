@@ -6,6 +6,8 @@ per-layer metric sits in a file of its own, found by the name that
 ``BENCHMARK.json`` gives it:
 
 * ``bench/configs/<config>.json``  — model sizes, the cut, limits;
+* ``bench/layers/<kind>.py``       — one file per layer kind that the
+  configuration's stack names (weights, reference, cost, program layout);
 * ``bench/traffic/<traffic>.json`` — the generator's kind and parameters;
 * ``bench/metrics/<metric>.py``    — one reader per per-layer metric.
 """

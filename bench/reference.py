@@ -3,11 +3,13 @@ its gradient and AdamW) in straightforward ``jax.numpy`` and float32, with
 no kernels, cache or batching, from the published descriptions.  Nothing
 here imports the program.
 
-A dense model applies granite's multipliers as its configuration file
-states them: the embedding times ``embedding_multiplier``, attention
-scores times ``attention_multiplier``, each block's output times
-``residual_multiplier`` before it joins the residual, and the logits over
-``logits_scaling``.  Where the program departs from the published model,
+A model is its list of layers (``bench/layers``): each layer applies its
+kinds' reference functions in turn, found by name.  Granite's multipliers
+apply as the configuration file states them: the embedding times
+``embedding_multiplier``, attention scores times ``attention_multiplier``,
+each block's output times ``residual_multiplier`` before it joins the
+residual, and the logits over ``logits_scaling`` (each 1 where the file
+does not state it).  Where the program departs from the published model,
 the file says so (``departures``) and the reference follows the file.
 
 A matrix product goes through ``mm``: ``"f32"`` is float32 at the highest
@@ -23,7 +25,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from bench.weights import NORMS, flat, ssm_dims
+from bench.layers import frozen, kind, layer_parts, norm_names, rows
+from bench.weights import flat
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -61,93 +64,26 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
     return x * jnp.cos(emb) + rot * jnp.sin(emb)
 
 
-# ---------------------------------------------------------------------------
-# dense GQA transformer (granite)
-# ---------------------------------------------------------------------------
-
-
-def dense_layer(x: jax.Array, lw: dict, m: dict, mode: str) -> jax.Array:
-    b, s, _ = x.shape
-    H, KV, hd = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
-    eps = m["rms_norm_eps"]
-    h = rms_norm(x, lw["ln_attn"], eps)
-    q = rope(mm(h, lw["q"], mode).reshape(b, s, H, hd), m["rope_theta"])
-    k = rope(mm(h, lw["k"], mode).reshape(b, s, KV, hd), m["rope_theta"])
-    v = mm(h, lw["v"], mode).reshape(b, s, KV, hd)
-    k = jnp.repeat(k, H // KV, axis=2)       # query head i reads kv head i // (H/KV)
-    v = jnp.repeat(v, H // KV, axis=2)
-    scores = (jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST)
-              * m["attention_multiplier"])
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    p = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HIGHEST).reshape(b, s, H * hd)
-    res = m["residual_multiplier"]
-    x = x + res * mm(o, lw["o"], mode)
-    h = rms_norm(x, lw["ln_mlp"], eps)
-    return x + res * mm(jax.nn.silu(mm(h, lw["gate"], mode))
-                        * mm(h, lw["up"], mode), lw["down"], mode)
-
-
-# ---------------------------------------------------------------------------
-# Mamba-2 (SSD), token by token
-# ---------------------------------------------------------------------------
-
-
-def ssm_layer(x: jax.Array, lw: dict, m: dict, mode: str) -> jax.Array:
-    b, s, _ = x.shape
-    dims = ssm_dims(m)
-    di, H, gn, P = dims["d_inner"], dims["heads"], dims["gn"], m["head_dim"]
-    G, N, W = m["n_groups"], m["state_size"], m["conv_kernel"]
-    h = rms_norm(x, lw["ln"], m["rms_norm_eps"])
-    zxbcdt = mm(h, lw["in_proj"], mode)
-    z = zxbcdt[..., :di]
-    xbc = zxbcdt[..., di:2 * di + 2 * gn]
-    dt = zxbcdt[..., 2 * di + 2 * gn:]
-    # depthwise causal convolution: the last tap meets the current token
-    pad = jnp.pad(xbc, ((0, 0), (W - 1, 0), (0, 0)))
-    cw = lw["conv_w"].astype(F32)
-    conv = sum(pad[:, i:i + s] * cw[i] for i in range(W))
-    xbc = jax.nn.silu(conv + lw["conv_b"].astype(F32))
-    xs = xbc[..., :di].reshape(b, s, H, P)
-    bs = jnp.repeat(xbc[..., di:di + gn].reshape(b, s, G, N), H // G, axis=2)
-    cs = jnp.repeat(xbc[..., di + gn:].reshape(b, s, G, N), H // G, axis=2)
-    dt = jax.nn.softplus(dt + lw["dt_bias"])                 # (B, S, H)
-    a = -jnp.exp(lw["A_log"])                                 # (H,)
-
-    def step(state, inp):
-        xt, bt, ct, dtt = inp                                 # (B,H,P) (B,H,N) (B,H,N) (B,H)
-        state = (jnp.exp(dtt * a)[..., None, None] * state
-                 + (dtt[..., None] * xt)[..., None] * bt[:, :, None, :])
-        return state, jnp.einsum("bhpn,bhn->bhp", state, ct, precision=HIGHEST)
-
-    seq = (xs.swapaxes(0, 1), bs.swapaxes(0, 1), cs.swapaxes(0, 1),
-           dt.swapaxes(0, 1))
-    _, y = jax.lax.scan(step, jnp.zeros((b, H, P, N), F32), seq)
-    y = y.swapaxes(0, 1) + lw["D"][:, None] * xs              # (B, S, H, P)
-    y = y.reshape(b, s, di) * jax.nn.silu(z)
-    y = rms_norm(y, lw["norm"], m["rms_norm_eps"])
-    return x + mm(y, lw["out_proj"], mode)
-
-
-_LAYER = {"dense": dense_layer, "ssm": ssm_layer}
-
-
 def _layer_at(layers: dict, i) -> dict:
     return jax.tree.map(lambda a: a[i], layers)
 
 
+def _kind_weights(layers: dict, name: str) -> dict:
+    """The stacked weights of kind ``name`` out of the flat ``layers``."""
+    return {k: layers[k] for k in kind(name).LAYOUT}
+
+
 @functools.lru_cache(maxsize=None)
-def _jitted(family: str, frozen: tuple, mode: str):
-    m = dict(frozen)
-    layer = _LAYER[family]
+def _jitted(frozen_m: tuple, mode: str):
+    m = dict(frozen_m)
 
     @jax.jit
     def embed(w_embed, tokens):
         return w_embed[tokens].astype(F32) * m.get("embedding_multiplier", 1.0)
 
-    @jax.jit
-    def one_layer(x, layers, i):
-        return layer(x, _layer_at(layers, i), m, mode)
+    @functools.partial(jax.jit, static_argnums=(0,))
+    def one_part(name, x, stacked, row):
+        return kind(name).forward(x, _layer_at(stacked, row), m, mode)
 
     @jax.jit
     def head(x, ln_f, w_embed, read):
@@ -158,23 +94,20 @@ def _jitted(family: str, frozen: tuple, mode: str):
         at = jnp.take_along_axis(logits, read[..., None], -1)[..., 0]
         return best, at, jnp.argmax(logits, -1)
 
-    return embed, one_layer, head
-
-
-def _frozen(m: dict) -> tuple:
-    return tuple(sorted((k, v) for k, v in m.items()
-                        if isinstance(v, (int, float, str))))
+    return embed, one_part, head
 
 
 def logits_summary(w: dict, m: dict, tokens: jax.Array, read: jax.Array,
                    mode: str = "f32") -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Run the model over ``tokens`` (B, S) layer by layer.  Returns, per
-    position, the best logit, the logit of the token ``read`` names, and
-    the first token."""
-    embed, one_layer, head = _jitted(m["family"], _frozen(m), mode)
+    """Run the model over ``tokens`` (B, S) layer by layer, each layer's
+    kinds in turn.  Returns, per position, the best logit, the logit of
+    the token ``read`` names, and the first token."""
+    embed, one_part, head = _jitted(frozen(m), mode)
     x = embed(w["embed"], tokens)
-    for i in range(m["num_hidden_layers"]):
-        x = one_layer(x, w["layers"], i)
+    at = rows(m)
+    for i, parts in enumerate(layer_parts(m)):
+        for name in parts:
+            x = one_part(name, x, _kind_weights(w["layers"], name), at[name][i])
     return head(x, w["ln_f"], w["embed"], read)
 
 
@@ -183,15 +116,18 @@ def logits_summary(w: dict, m: dict, tokens: jax.Array, read: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def dense_loss(w: dict, inputs: jax.Array, targets: jax.Array, m: dict,
-               z_loss: float, mode: str = "f32") -> jax.Array:
+def lm_loss(w: dict, inputs: jax.Array, targets: jax.Array, m: dict,
+            z_loss: float, mode: str = "f32") -> jax.Array:
     """Mean over tokens of cross entropy plus ``z_loss`` * logsumexp^2."""
-    x = w["embed"][inputs].astype(F32) * m["embedding_multiplier"]
-    layer = jax.checkpoint(lambda x, lw: dense_layer(x, lw, m, mode))
-    for i in range(m["num_hidden_layers"]):
-        x = layer(x, _layer_at(w["layers"], i))
+    x = w["embed"][inputs].astype(F32) * m.get("embedding_multiplier", 1.0)
+    at = rows(m)
+    for i, parts in enumerate(layer_parts(m)):
+        for name in parts:
+            layer = jax.checkpoint(functools.partial(kind(name).forward, m=m,
+                                                     mode=mode))
+            x = layer(x, _layer_at(_kind_weights(w["layers"], name), at[name][i]))
     h = rms_norm(x, w["ln_f"], m["rms_norm_eps"])
-    logits = mm(h, w["embed"].T, mode) / m["logits_scaling"]
+    logits = mm(h, w["embed"].T, mode) / m.get("logits_scaling", 1.0)
     lse = jax.nn.logsumexp(logits, -1)
     gold = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
     return jnp.mean(lse - gold + z_loss * lse ** 2)
@@ -203,7 +139,7 @@ def _row_grad(frozen: tuple, z_loss: float, mode: str):
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def acc_grad(acc, w, inputs, targets, weight):
-        loss, g = jax.value_and_grad(dense_loss)(w, inputs, targets, m,
+        loss, g = jax.value_and_grad(lm_loss)(w, inputs, targets, m,
                                                  z_loss, mode)
         return jax.tree.map(lambda a, b: a + weight * b, acc, g), loss
 
@@ -213,7 +149,7 @@ def _row_grad(frozen: tuple, z_loss: float, mode: str):
 def loss_and_grad(w: dict, batch: dict, m: dict, z_loss: float,
                   mode: str = "f32"):
     """Mean loss and gradient over the batch's rows, one row at a time."""
-    acc_grad = _row_grad(_frozen(m), float(z_loss), mode)
+    acc_grad = _row_grad(frozen(m), float(z_loss), mode)
     rows = batch["inputs"].shape[0]
     acc = jax.tree.map(jnp.zeros_like, w)
     losses = []
@@ -285,7 +221,8 @@ def adamw_steps(w: dict, batches: list[dict], m: dict, opt: dict, *,
     the last step.  ``w`` is consumed."""
     mu = jax.tree.map(jnp.zeros_like, w)
     nu = jax.tree.map(jnp.zeros_like, w)
-    decay_mask = tuple(getattr(path[-1], "key", None) not in NORMS
+    norms_ = norm_names(m)
+    decay_mask = tuple(getattr(path[-1], "key", None) not in norms_
                        for path, _ in jax.tree_util.tree_leaves_with_path(w))
     losses, first_grad = [], None
     for count, batch in enumerate(batches, start=1):

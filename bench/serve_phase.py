@@ -111,7 +111,7 @@ def build(config: dict, traffic: dict, seed: int, spans: Spans,
         raise BenchError("the mix's longest request overruns max_len")
     cfg = program_config(config)
     with spans.span("setup.weights"):
-        params = jax.block_until_ready(weights.program_weights(m, seed))
+        params = jax.block_until_ready(weights.program_weights(m, cfg, seed))
     with spans.span("setup.backend"):
         backend = BenchDecodeBackend(
             cfg, params, spans=spans, max_batch=serve["max_batch"],

@@ -51,13 +51,6 @@ from bench.trace_reduce import WINDOW_SPAN  # noqa: E402
 #: the readers of the program's spans and scopes
 NEW_METRICS = ("step_host_ms.serve", "plane_host_ms.serve",
                "queue_wait_ms.serve", "decode_state_share.serve")
-#: the compiler's names for the decode cache's slices and writes in the
-#: granite-serve-gen program, as the cell's traces list its top ops
-NAMED_CACHE_OPS = ("dynamic_update_slice.31", "dynamic_update_slice.32",
-                   "bitcast_dynamic-update-slice_fusion.4",
-                   "bitcast_dynamic-update-slice_fusion.5",
-                   "dynamic-slice_bitcast_fusion.4",
-                   "dynamic-slice_bitcast_fusion.5")
 
 
 # ---------------------------------------------------------------------------
@@ -272,7 +265,6 @@ def traced_run(rig, cell, seed: int, seconds: float, peaks: dict,
                   if s["name"] == "serve.step" and window[0] <= s["start"]
                   and s["end"] <= stop["t"]]
     busy = trace["busy_s"]
-    named = sum(t for name, t in trace["device_ops"] if name in NAMED_CACHE_OPS)
     offset = clock_offset(record, module)
     return {
         "window": list(window), "trace_stop_s": tracer.stop_s,
@@ -284,7 +276,6 @@ def traced_run(rig, cell, seed: int, seconds: float, peaks: dict,
             "serve_step_mean_ms": 1e3 * statistics.fmean(step_spans),
             "decode_step_ms_traced": 1e3 * statistics.fmean(
                 s["t_return"] - s["t_call"] for s in traced_steps),
-            "named_cache_ops_share": 100.0 * named / busy if busy else None,
             "layer_weights_share": (100.0 * scopes["layer_weights"] / busy
                                     if busy and scopes else None),
         },

@@ -18,6 +18,7 @@ writes nothing to disk.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import shutil
 import statistics
@@ -78,10 +79,12 @@ class TrainProbe:
     """Wraps one supervisor's per-step calls; records shard calls, step
     ends, the first steps' readings, and opens and closes the window."""
 
-    def __init__(self, sup: WrathTrainSupervisor, family: str, p0: dict, *,
+    def __init__(self, sup: WrathTrainSupervisor, to_bench, p0: dict, *,
                  checked: int, seconds: float, spans: Spans, tracer=None,
                  trace_steps: int = 0, closes_after: int = 0):
-        self.sup, self.family, self.p0 = sup, family, p0
+        #: a tree shaped like the program's parameters, as {benchmark name: leaf}
+        self.to_bench = to_bench
+        self.sup, self.p0 = sup, p0
         self.checked, self.seconds = checked, seconds
         #: the window holds at least the steps before this one
         self.closes_after = closes_after
@@ -145,12 +148,11 @@ class TrainProbe:
             # the first gradient as the optimizer got it: m = (1 - b1) g
             b1 = opt_cfg.b1
             self.first_grad = {name: float(v) / (1 - b1) for name, v in
-                               weights.flat_from_program(
-                                   reference.norms(out[1]["m"]), self.family).items()}
+                               reference.norms(self.to_bench(out[1]["m"])).items()}
         if k == self.checked - 1:
             self.change = {name: float(v) for name, v in
-                           weights.flat_from_program(
-                               reference.diff_norms(out[0], self.p0), self.family).items()}
+                           reference.diff_norms(self.to_bench(out[0]),
+                                                self.to_bench(self.p0)).items()}
             self.p0 = None
             # the window opens once the readings above are taken
             t = self.step_ends[k] = time.perf_counter()
@@ -226,7 +228,7 @@ def run_setup(config: dict, traffic: dict, seed: int, *, seconds: float,
     the first steps and the window.  Returns the probe and supervisor."""
     m = config["model"]
     cfg = program_config(config)
-    p0 = weights.to_program(weights.make_weights(m, seed), m["family"])
+    p0 = weights.to_program(weights.make_weights(m, seed), m, cfg)
     weights.check_layout(p0, abstract(param_defs(cfg)))
     ckpt_dir = tempfile.mkdtemp(prefix="bench_ckpt_")
     try:
@@ -241,7 +243,8 @@ def run_setup(config: dict, traffic: dict, seed: int, *, seconds: float,
         # the window runs on to the step after the last host loss, so it
         # holds the loss and the recovery however slow the steps are
         last = max((e.step for e in events), default=checked - 1)
-        with TrainProbe(sup, m["family"], p0, checked=checked, seconds=seconds,
+        to_bench = functools.partial(weights.flat_from_program, m=m, cfg=cfg)
+        with TrainProbe(sup, to_bench, p0, checked=checked, seconds=seconds,
                         spans=spans, tracer=tracer, trace_steps=trace_steps,
                         closes_after=last + 1) as probe:
             probe.warm(p0, sizes)

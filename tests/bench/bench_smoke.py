@@ -12,13 +12,15 @@ if str(ROOT) not in sys.path:
 
 from bench.harness import Cell, CompileClock, load_json  # noqa: E402
 
+DATA = Path(__file__).resolve().parent / "data"
 #: small widths; the init scale keeps logits about as wide as at full size
 SMOKE_MODEL = {"num_hidden_layers": 2, "hidden_size": 64,
                "num_attention_heads": 4, "num_key_value_heads": 2,
                "head_dim": 16, "intermediate_size": 128, "vocab_size": 256,
                "initializer_range": 0.11,
                # the program scales attention scores by 1/sqrt(head_dim)
-               "attention_multiplier": 0.25}
+               "attention_multiplier": 0.25,
+               "mamba_d_head": 16, "mamba_n_heads": 8, "mamba_d_state": 16}
 PEAKS = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11}
 
 
@@ -36,16 +38,21 @@ class NoTracer:
         return None
 
 
-def smoke_cell(config: str, traffic: str) -> Cell:
-    """A cell of a committed configuration and traffic mix, at smoke size."""
-    cell = Cell(name=f"{config}.{traffic}", chips=1,
-                config=copy.deepcopy(load_json(ROOT / "bench/configs" / f"{config}.json")),
+def smoke_cell(config: str | Path, traffic: str) -> Cell:
+    """A cell of a committed configuration (by name, or a file) and
+    traffic mix, at smoke size: each size the file states is cut, and a
+    width it states as 0 (no MLP) stays 0.  A file that lists its
+    ``layer_types`` keeps its stack."""
+    path = config if isinstance(config, Path) else ROOT / "bench/configs" / f"{config}.json"
+    cell = Cell(name=f"{path.stem}.{traffic}", chips=1,
+                config=copy.deepcopy(load_json(path)),
                 traffic=load_json(ROOT / "bench/traffic" / f"{traffic}.json"),
                 end_to_end=[], per_layer=[])
-    cell.config["model"].update(SMOKE_MODEL)
-    if cell.config["model"]["family"] == "ssm":
-        del cell.config["model"]["attention_multiplier"]
-        cell.config["model"]["state_size"] = 16
+    model = cell.config["model"]
+    cut = {k: v for k, v in SMOKE_MODEL.items() if model.get(k)}
+    if "layer_types" in model:
+        del cut["num_hidden_layers"]
+    model.update(cut)
     if cell.config["plane"] == "serve":
         cell.config["serve"] = {"max_batch": 4, "max_len": 32, "replicas": 1}
         cell.config["limits"]["served_tokens_compared"] = 8

@@ -12,11 +12,13 @@ from types import SimpleNamespace
 
 import pytest
 
-from bench_smoke import ROOT, committed
-from bench import cost, trace_reduce
+from bench_smoke import DATA, ROOT, committed
+from bench import cost, trace_reduce, weights
 from bench.harness import (BenchError, load_peaks, load_reader, percentile,
                            resolve_cell)
+from bench.program import program_config
 from bench.serve_phase import end_to_end
+from repro.models import abstract, param_defs
 
 SPEC = committed("BENCHMARK.json")
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -45,13 +47,21 @@ def test_a_cell_and_a_metric_added_as_new_files_only(tmp_path):
     (tmp_path / "bench/traffic/serve-mid.json").write_text(json.dumps(mix))
     (tmp_path / "bench/metrics/served_tokens.py").write_text(
         "def read(run):\n    return float(sum(s['occupied'] for s in run['steps']))\n")
+    # a configuration of another layer mix: Mamba-2 and attention layers
+    shutil.copy(DATA / "hybrid-tiny.json", tmp_path / "bench/configs/hybrid-tiny.json")
     # the new entries
     spec = json.loads((tmp_path / "BENCHMARK.json").read_text())
-    spec["workloads"].append({"name": "granite-serve-mid", "config": "granite-3-2b",
-                              "traffic": "serve-mid", "chips": 1, "why": "test"})
+    spec["configs"].append({"name": "hybrid-tiny", "source": "test",
+                            "file": "bench/configs/hybrid-tiny.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"] += [
+        {"name": "granite-serve-mid", "config": "granite-3-2b",
+         "traffic": "serve-mid", "chips": 1, "why": "test"},
+        {"name": "hybrid-serve-gen", "config": "hybrid-tiny",
+         "traffic": "serve-gen", "chips": 1, "why": "test"}]
     for m in spec["end_to_end"]:
         if "granite-serve-gen" in m.get("workloads", ()):
-            m["workloads"].append("granite-serve-mid")
+            m["workloads"] += ["granite-serve-mid", "hybrid-serve-gen"]
     spec["per_layer"].append({"name": "served_tokens", "unit": "tokens",
                               "better": "higher", "source": "program_counter",
                               "layer": "batcher", "moves": "serve_tokens_per_s",
@@ -68,6 +78,14 @@ def test_a_cell_and_a_metric_added_as_new_files_only(tmp_path):
     # the new metric is not asked of the cells that do not list it
     assert "served_tokens" not in {m["name"] for m in
                                    resolve_cell("granite-serve-gen", tmp_path).per_layer}
+    # the hybrid resolves and builds: its program, and its weights in the
+    # program's tree
+    hybrid = resolve_cell("hybrid-serve-gen", tmp_path)
+    cfg = program_config(hybrid.config)
+    assert {mixer for mixer, _ in cfg.pattern} == {"ssd", "attn"}
+    weights.check_layout(weights.program_weights(hybrid.config["model"], cfg, 1),
+                         abstract(param_defs(cfg)))
+    assert {m["name"] for m in hybrid.per_layer} >= {"slot_occupancy", "mfu.serve"}
     after = {p.relative_to(tmp_path): p.read_bytes()
              for p in (tmp_path / "bench").rglob("*") if p.is_file()}
     assert all(after[k] == v for k, v in before.items())   # no file edited

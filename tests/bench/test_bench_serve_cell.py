@@ -2,14 +2,26 @@
 plane function: the batch client's fresh-state reset, the served tokens
 against the reference, and ``correct`` turning false when the timed path
 is broken underneath."""
+from pathlib import Path
+
 import pytest
 
-from bench_smoke import run_cell, smoke_cell
+from bench_smoke import DATA, run_cell, smoke_cell
 from bench import serve_phase as sp
 from bench.harness import Spans
 from bench.traffic_gen import closed_batch, steps_of
 
 CELL = ("granite-3-2b", "serve-gen")
+HYBRID = (DATA / "hybrid-tiny.json", "serve-gen")
+#: stacks of other layer kinds, with the limit their smoke runs are held
+#: to for the float8 control: sound runs read 0.023 (mamba2) and 0.020
+#: (the hybrid), the control 0.130 and 0.693
+OTHER_STACKS = {"mamba2-780m": (("mamba2-780m", "serve-gen"), 0.06),
+                "hybrid-tiny": (HYBRID, 0.1)}
+
+
+def _cell_id(cell):
+    return f"{Path(cell[0]).stem}-{cell[1]}"
 
 
 def test_each_batch_starts_whole_on_a_fresh_state():
@@ -32,8 +44,8 @@ def test_each_batch_starts_whole_on_a_fresh_state():
 
 
 @pytest.mark.parametrize("cell", [CELL, ("granite-3-2b", "serve-prompt"),
-                                  ("mamba2-780m", "serve-gen")],
-                         ids=lambda c: "-".join(c))
+                                  ("mamba2-780m", "serve-gen"), HYBRID],
+                         ids=_cell_id)
 def test_a_sound_run_is_correct(cell):
     out = run_cell(smoke_cell(*cell))
     assert out["correct"], out["checks"]
@@ -61,13 +73,27 @@ def _half_batch(self, replica, inputs):
 
 
 _orig_step = sp.BenchDecodeBackend.step
+FAULTS = pytest.mark.parametrize(
+    "fault", [_altered, _state_unchanged, _half_batch],
+    ids=["token_altered", "state_unchanged", "half_batch"])
 
 
-@pytest.mark.parametrize("fault", [_altered, _state_unchanged, _half_batch],
-                         ids=["token_altered", "state_unchanged", "half_batch"])
+@FAULTS
 def test_a_broken_timed_path_is_not_correct(monkeypatch, fault):
     monkeypatch.setattr(sp.BenchDecodeBackend, "step", fault)
     out = run_cell(smoke_cell(*CELL))
+    assert not out["correct"]
+    assert not out["checks"]["served_logit_gap"]["ok"]
+
+
+@FAULTS
+@pytest.mark.parametrize("stack", OTHER_STACKS)
+def test_a_broken_timed_path_of_another_stack_is_not_correct(monkeypatch, stack,
+                                                             fault):
+    # at the file's own limit: the SSD state and a hybrid's two kinds of
+    # state side by side
+    monkeypatch.setattr(sp.BenchDecodeBackend, "step", fault)
+    out = run_cell(smoke_cell(*OTHER_STACKS[stack][0]))
     assert not out["correct"]
     assert not out["checks"]["served_logit_gap"]["ok"]
 
@@ -81,6 +107,22 @@ def test_the_float8_control_is_not_correct(monkeypatch):
     def cell():
         c = smoke_cell(*CELL)
         c.config["limits"]["served_logit_gap"] = 0.1
+        return c
+
+    assert run_cell(cell())["correct"]
+    monkeypatch.setattr(sp, "served_gap", sp.control_gap)
+    out = run_cell(cell())
+    assert not out["correct"]
+    assert not out["checks"]["served_logit_gap"]["ok"], out["checks"]
+
+
+@pytest.mark.parametrize("stack", OTHER_STACKS)
+def test_the_float8_control_fails_another_stack(monkeypatch, stack):
+    cell_of, limit = OTHER_STACKS[stack]
+
+    def cell():
+        c = smoke_cell(*cell_of)
+        c.config["limits"]["served_logit_gap"] = limit
         return c
 
     assert run_cell(cell())["correct"]

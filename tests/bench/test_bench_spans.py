@@ -127,7 +127,19 @@ def test_program_text_names_the_state_parameters(decode_program):
         assert "op_name=\"c[" in line
 
 
+def _result_shape(hlo: str, name: str) -> tuple | None:
+    """The dims of instruction ``name``'s array result in the HLO text."""
+    m = re.search(rf"^\s*(?:ROOT\s+)?%?{re.escape(name)}\s*=\s*\w+\[([\d,]*)\]",
+                  hlo, re.M)
+    return tuple(int(d) for d in m.group(1).split(",") if d) if m else None
+
+
 def test_every_op_of_the_decode_layer_scan_has_a_class(decode_program):
+    import jax
+
+    from repro.configs import get_smoke_config
+    from repro.models import abstract, cache_defs
+
     decode_hlo, state, _, _ = decode_program
     scopes = scope_reduce.hlo_scopes(decode_hlo, state)
     comps, _, _ = scope_reduce._computations(decode_hlo)
@@ -137,7 +149,17 @@ def test_every_op_of_the_decode_layer_scan_has_a_class(decode_program):
     classes = {ins["name"]: scopes[ins["name"]] for ins in body_ops}
     assert set(classes.values()) <= {"kv_write", "layer_state",
                                      "layer_weights", "attn", "ffn", "other"}
-    assert {"kv_write", "layer_state", "attn", "ffn"} <= set(classes.values())
+    assert {"kv_write", "attn", "ffn"} <= set(classes.values())
+    # where the scan's own ops move the decode state (a result shaped as a
+    # state leaf or one layer of it, as this CPU program's are and the v5e
+    # program's no longer are), they are layer_state, and only then is
+    # that class required
+    state_shapes = set()
+    for leaf in jax.tree.leaves(abstract(cache_defs(
+            get_smoke_config("granite_3_2b"), 4, 32))):
+        if leaf.ndim >= 2:
+            state_shapes |= {leaf.shape, leaf.shape[1:], (1,) + leaf.shape[1:]}
+    moves_state = []
     for ins in body_ops:
         if ins["opcode"] in scope_reduce.CONTROL:
             continue
@@ -145,6 +167,10 @@ def test_every_op_of_the_decode_layer_scan_has_a_class(decode_program):
         own = scope_reduce.scope_class(ins["op_name"])
         if own not in (None, "layers"):
             assert classes[ins["name"]] == own, ins
+        elif own == "layers" and _result_shape(decode_hlo, ins["name"]) in state_shapes:
+            moves_state.append(ins["name"])
+    assert all(classes[n] == "layer_state" for n in moves_state)
+    assert ("layer_state" in classes.values()) == bool(moves_state)
     # the cache writes hold the attention's dynamic-update-slices
     assert any("dynamic-update-slice" in n or "dynamic_update_slice" in n
                for n, c in classes.items() if c == "kv_write")

@@ -51,6 +51,7 @@ SYSTEM_EVENTS = frozenset({
 })
 
 GAUGES = frozenset({
+    'serve.decode_state_bytes',
     'serve.queue_depth',
 })
 

@@ -18,6 +18,7 @@ ARCH_IDS = (
     "olmoe_1b_7b",
     "mamba2_780m",
     "recurrentgemma_9b",
+    "granite_4_0_h_micro",
 )
 
 # canonical dashed ids (CLI spelling) -> module names

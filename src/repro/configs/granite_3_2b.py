@@ -8,9 +8,13 @@ CONFIG = ModelConfig(
     d_ff=8192, vocab_size=49155, head_dim=64,
     pattern=(("attn", "dense"),),
     tie_embeddings=True,
+    # the scale the program has always run, 1/sqrt(64), as the benchmark's
+    # file states it; the published multipliers are not run yet
+    attention_multiplier=0.125,
 )
 
 
 def smoke_config() -> ModelConfig:
     return CONFIG.scaled(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
-                         d_ff=128, vocab_size=256, head_dim=16)
+                         d_ff=128, vocab_size=256, head_dim=16,
+                         attention_multiplier=None)

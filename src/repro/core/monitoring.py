@@ -393,6 +393,10 @@ class PlacementStats:
         return self.duration_sum / self.duration_n if self.duration_n else 0.0
 
 
+def _gauge_key(name: str, label: str | None) -> str:
+    return name if label is None else f"{name}[{label}]"
+
+
 class MonitoringDatabase:
     """Thread-safe centralized store + query API (paper §IV).
 
@@ -549,13 +553,17 @@ class MonitoringDatabase:
         with self._lock:
             self.failures.append(report)
 
-    def record_gauge(self, name: str, value: float) -> None:
+    def record_gauge(self, name: str, value: float, *,
+                     label: str | None = None) -> None:
         """Observe one sample of a named scalar gauge (queue depth, live
-        replicas).  O(1); ring-bounded like every store."""
+        replicas); a ``label`` keeps a sample series of its own under the
+        name (``serve.decode_state_bytes`` by kind of state).  O(1);
+        ring-bounded like every store."""
+        key = _gauge_key(name, label)
         with self._lock:
             value = float(value)
-            self._gauges[name].push(value)
-            self._gauge_rings[name].append((self._time(), value))
+            self._gauges[key].push(value)
+            self._gauge_rings[key].append((self._time(), value))
 
     # -- queries -------------------------------------------------------------
     def last_heartbeats(self) -> dict[str, float]:
@@ -640,10 +648,11 @@ class MonitoringDatabase:
             return 0.0
         return stats.p95
 
-    def gauge_stats(self, name: str) -> StreamingStats | None:
+    def gauge_stats(self, name: str, *,
+                    label: str | None = None) -> StreamingStats | None:
         """Streaming profile of a named gauge (None = never observed)."""
         with self._lock:
-            stats = self._gauges.get(name)
+            stats = self._gauges.get(_gauge_key(name, label))
             return stats if stats is not None and stats.n else None
 
     def recent_gauges(self, name: str, k: int = 16) -> list[tuple[float, float]]:

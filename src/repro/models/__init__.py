@@ -1,6 +1,6 @@
 """Unified model zoo: one config system covering dense GQA, SWA hybrids,
 MLA+MoE, classic MoE, Mamba-2 SSD, RG-LRU hybrids, enc-dec and VLM
-backbones (the 10 assigned architectures)."""
+backbones, and Mamba-2/attention hybrids (the registered architectures)."""
 from repro.models.config import (
     BlockKind,
     MLACfg,
@@ -11,6 +11,7 @@ from repro.models.config import (
 )
 from repro.models.model import (
     cache_defs,
+    decode_state_bytes,
     decode_step,
     forward_train,
     loss_fn,
@@ -27,7 +28,8 @@ from repro.models.spec import (
 
 __all__ = [
     "ModelConfig", "MoECfg", "MLACfg", "SSMCfg", "RGLRUCfg", "BlockKind",
-    "param_defs", "cache_defs", "forward_train", "loss_fn", "decode_step",
+    "param_defs", "cache_defs", "decode_state_bytes", "forward_train",
+    "loss_fn", "decode_step",
     "ParamDef", "abstract", "logical_axes", "materialize",
     "param_count", "param_bytes",
 ]

@@ -18,6 +18,7 @@ lowered HLO is O(#distinct segments), not O(#layers).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Any
 
@@ -27,6 +28,7 @@ BlockKind = tuple[Mixer, Ffn]
 
 MIXERS = ("attn", "swa", "mla", "ssd", "rglru", "bidir")
 FFNS = ("dense", "moe", "none")
+POSITION_EMBEDDINGS = ("rope", "nope")
 
 
 @dataclass(frozen=True)
@@ -114,6 +116,17 @@ class ModelConfig:
     # long-context support marker (sub-quadratic path exists) — drives the
     # long_500k shape-skip logic (DESIGN.md §4)
     subquadratic: bool = False
+    # granite's multipliers (granitemoehybrid's config.json names): the
+    # embedding times ``embedding_multiplier``, attention scores times
+    # ``attention_multiplier`` (None -> 1/sqrt(head_dim)), every mixer and
+    # FFN output times ``residual_multiplier`` before the residual, the
+    # logits over ``logits_scaling``.  A multiplier of exactly 1 adds no op.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # "rope" (rotary on q and k) or "nope" (no position embedding)
+    position_embedding_type: str = "rope"
 
     # ------------------------------------------------------------------ #
     @property
@@ -125,6 +138,13 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def attn_scale(self) -> float:
+        """The attention scores' scale (MLA keeps its own)."""
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return 1.0 / math.sqrt(self.resolved_head_dim)
 
     @property
     def q_group(self) -> int:
@@ -182,6 +202,9 @@ class ModelConfig:
             raise ValueError("rglru pattern requires rglru config")
         if self.input_kind not in ("tokens", "embeds"):
             raise ValueError(f"bad input_kind {self.input_kind!r}")
+        if self.position_embedding_type not in POSITION_EMBEDDINGS:
+            raise ValueError(
+                f"bad position_embedding_type {self.position_embedding_type!r}")
 
     def scaled(self, **overrides: Any) -> "ModelConfig":
         """Reduced-config variant for smoke tests."""

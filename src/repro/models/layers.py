@@ -168,12 +168,14 @@ def _pick_q_block(s: int) -> int:
 
 def mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
         causal: bool, window: int = 0, q_offset: jax.Array | int = 0,
-        kv_len: jax.Array | None = None) -> jax.Array:
+        kv_len: jax.Array | None = None,
+        scale: float | None = None) -> jax.Array:
     """Dense attention with GQA and optional sliding window.
 
     q: (B, Sq, H, D); k, v: (B, Sk, KV, D).  Returns (B, Sq, H, D).
     ``q_offset``: absolute position of q[0] (decode / blockwise).
     ``kv_len``: number of valid kv positions (ring-buffer decode).
+    ``scale``: the scores' scale (None -> 1/sqrt(D)).
     """
     b, sq, h, d = q.shape
     _, sk, kvh, _ = k.shape
@@ -182,7 +184,7 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qh = q.reshape(b, sq, kvh, g, d)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qh, k,
                         preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(d)
+    scores = scores * (scale if scale is not None else 1.0 / math.sqrt(d))
     qpos = (jnp.arange(sq) + q_offset)[:, None]        # (Sq, 1)
     kpos = jnp.arange(sk)[None, :]                     # (1, Sk)
     mask = jnp.ones((sq, sk), dtype=bool)
@@ -199,7 +201,8 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def blockwise_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                  causal: bool = True, window: int = 0) -> jax.Array:
+                  causal: bool = True, window: int = 0,
+                  scale: float | None = None) -> jax.Array:
     """Scan over query blocks; O(qb·S) live scores instead of O(S²).
 
     For sliding-window attention only the (window + qb)-wide KV slice is
@@ -209,7 +212,7 @@ def blockwise_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
     dv = v.shape[-1]
     qb = _pick_q_block(s)
     if qb == s:
-        return mha(q, k, v, causal=causal, window=window)
+        return mha(q, k, v, causal=causal, window=window, scale=scale)
     nq = s // qb
     qblocks = q.reshape(b, nq, qb, h, d).swapaxes(0, 1)    # (nq, B, qb, H, D)
 
@@ -226,12 +229,13 @@ def blockwise_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
             ki = jax.lax.dynamic_slice_in_dim(k, start, ctx, axis=1)
             vi = jax.lax.dynamic_slice_in_dim(v, start, ctx, axis=1)
             out = mha(qi, ki, vi, causal=causal, window=window,
-                      q_offset=i * qb - start)
+                      q_offset=i * qb - start, scale=scale)
             return carry, out
     else:
         def body(carry, inp):
             i, qi = inp
-            out = mha(qi, k, v, causal=causal, window=window, q_offset=i * qb)
+            out = mha(qi, k, v, causal=causal, window=window, q_offset=i * qb,
+                      scale=scale)
             return carry, out
 
     body = jax.checkpoint(body, prevent_cse=False)
@@ -278,6 +282,8 @@ def attention_train(params: dict, x: jax.Array, cfg: ModelConfig, *,
     """Self- (or cross-) attention over a full sequence.
 
     kv_source: if given (encoder output), cross-attention without RoPE.
+    Self-attention takes RoPE unless ``cfg.position_embedding_type`` is
+    ``"nope"``; scores are scaled by ``cfg.attn_scale``.
     return_kv: also return the (roped) K/V for prefill cache capture,
     each (B, S, KV * hd) as the decode state holds them.
     """
@@ -288,7 +294,7 @@ def attention_train(params: dict, x: jax.Array, cfg: ModelConfig, *,
     q = (x @ params["wq"]).reshape(b, s, h, hd)
     k = (src @ params["wk"]).reshape(b, sk, kv, hd)
     v = (src @ params["wv"]).reshape(b, sk, kv, hd)
-    if kv_source is None:
+    if kv_source is None and cfg.position_embedding_type == "rope":
         pos = positions if positions is not None else jnp.arange(s)
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos if sk == s else jnp.arange(sk), cfg.rope_theta)
@@ -301,7 +307,8 @@ def attention_train(params: dict, x: jax.Array, cfg: ModelConfig, *,
     v = constrain(v, ("batch", "seq", "heads" if cfg.head_pad else "kv_heads",
                       None))
     causal = (kv_source is None) and not bidirectional
-    out = blockwise_mha(q, k, v, causal=causal, window=window)
+    out = blockwise_mha(q, k, v, causal=causal, window=window,
+                        scale=cfg.attn_scale)
     out = out[..., :h_orig, :]
     out = constrain(out, ("batch", "seq", "heads", None))
     out = out.reshape(b, s, h * hd) @ params["wo"]
@@ -384,11 +391,12 @@ def write_position(buf: jax.Array, new: jax.Array, layer: jax.Array | int,
 
 
 def _attend(q: jax.Array, k: jax.Array, v: jax.Array,
-            n_valid: jax.Array | int) -> jax.Array:
+            n_valid: jax.Array | int, scale: float) -> jax.Array:
     """One token's attention against one layer's folded K/V.
 
     q: (B, H, hd); k, v: (B, S, KV * hd), the KV heads side by side on
-    the minor axis; positions from ``n_valid`` on are masked.  Returns
+    the minor axis; positions from ``n_valid`` on are masked; scores are
+    scaled by ``scale``.  Returns
     (B, H * hd).  Each query head is laid on its own KV head's lanes of a
     zero row, so both products read the buffer as it lies, with no copy
     into a per-head layout; the products with the zeros add nothing."""
@@ -399,7 +407,7 @@ def _attend(q: jax.Array, k: jax.Array, v: jax.Array,
     rows = jnp.einsum("bkgd,kj->bkgjd", q.reshape(b, kvh, g, hd),
                       heads).reshape(b, h, kvh * hd)
     scores = jnp.einsum("bhe,bse->bhs", rows, k,
-                        preferred_element_type=jnp.float32) / math.sqrt(hd)
+                        preferred_element_type=jnp.float32) * scale
     scores = jnp.where(jnp.arange(smax) < n_valid, scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhs,bse->bhe", p, v).reshape(b, kvh, g, kvh, hd)
@@ -423,23 +431,25 @@ def attention_decode(params: dict, x: jax.Array, cache: dict,
     q = (x @ params["wq"]).reshape(b, 1, h, hd)
     if cross:
         k, v = layer_slice(cache["k"], layer), layer_slice(cache["v"], layer)
-        out = _attend(q[:, 0], k, v, k.shape[1])
+        out = _attend(q[:, 0], k, v, k.shape[1], cfg.attn_scale)
         return out[:, None] @ params["wo"], cache
 
     smax = cache["k"].shape[2]
     cur = layer_slice(cache["len"], layer)              # scalar int32
     k_new = (x @ params["wk"]).reshape(b, 1, kvh, hd)
     v_new = (x @ params["wv"]).reshape(b, 1, kvh * hd)
-    posq = jnp.full((1,), cur, dtype=jnp.int32)
-    q = apply_rope(q, posq, cfg.rope_theta)
-    k_new = apply_rope(k_new, posq, cfg.rope_theta).reshape(b, 1, kvh * hd)
+    if cfg.position_embedding_type == "rope":
+        posq = jnp.full((1,), cur, dtype=jnp.int32)
+        q = apply_rope(q, posq, cfg.rope_theta)
+        k_new = apply_rope(k_new, posq, cfg.rope_theta)
+    k_new = k_new.reshape(b, 1, kvh * hd)
     slot = jnp.mod(cur, smax)
     with jax.named_scope("kv_write"):
         ck = write_position(cache["k"], k_new, layer, slot)
         cv = write_position(cache["v"], v_new, layer, slot)
     # decode scores over the whole buffer; invalid slots masked via n_valid
     out = _attend(q[:, 0], layer_slice(ck, layer), layer_slice(cv, layer),
-                  jnp.minimum(cur + 1, smax))
+                  jnp.minimum(cur + 1, smax), cfg.attn_scale)
     new_cache = {"k": ck, "v": cv, "len": jax.lax.dynamic_update_index_in_dim(
         cache["len"], cur + 1, layer, 0)}
     return out[:, None] @ params["wo"], new_cache

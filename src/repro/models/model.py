@@ -68,6 +68,20 @@ def block_defs(cfg: ModelConfig, kind: BlockKind, *, cross: bool = False) -> dic
     return d
 
 
+def _residual(x: jax.Array, y: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """``y`` joins the residual ``x``, scaled by ``residual_multiplier``
+    (no op where it is 1)."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
+def _scale_embedding(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
 def _apply_ffn(params: dict, x: jax.Array, cfg: ModelConfig,
                kind: BlockKind) -> tuple[jax.Array, jax.Array]:
     _, ffn = kind
@@ -81,7 +95,7 @@ def _apply_ffn(params: dict, x: jax.Array, cfg: ModelConfig,
             y = swiglu(params["ffn"], h)
         else:
             y, aux = moe_mod.moe_ffn(params["moe"], h, cfg)
-        return x + y, aux
+        return _residual(x, y, cfg), aux
 
 
 def _mixer_scope(mixer: str) -> str:
@@ -107,7 +121,7 @@ def block_train(params: dict, x: jax.Array, cfg: ModelConfig, kind: BlockKind,
             y = ssm.ssd_block_train(params["ssd"], h, cfg)
         else:
             y = griffin.rglru_block_train(params["rglru"], h, cfg)
-        x = x + y
+        x = _residual(x, y, cfg)
     if enc_out is not None and "cross" in params:
         h = rms_norm(x, params["ln_x"], cfg.norm_eps)
         x = x + attention_train(params["cross"], h, cfg, kv_source=enc_out)
@@ -142,7 +156,7 @@ def block_decode(params: dict, x: jax.Array, state: dict,
             state[mixer] = jax.tree.map(
                 lambda s, n: jax.lax.dynamic_update_index_in_dim(
                     s, n.astype(s.dtype), layer, 0), state[mixer], new)
-        x = x + y
+        x = _residual(x, y, cfg)
     if "cross" in state and "cross" in params:
         h = rms_norm(x, params["ln_x"], cfg.norm_eps)
         y, _ = attention_decode(params["cross"], h, state["cross"], layer,
@@ -216,6 +230,24 @@ def _block_cache_defs(cfg: ModelConfig, kind: BlockKind, batch: int,
             "h": pdef((batch, "batch"), (w, "d_ff"), init="zeros"),
         }}
     raise ValueError(mixer)  # pragma: no cover
+
+
+def decode_state_bytes(state: Any) -> dict[str, int]:
+    """Bytes of a decode-state tree by kind of state: ``kv`` (attention's
+    K/V and positions, MLA's latents, cross-attention memory), ``conv``
+    (the recurrent mixers' convolution windows), ``ssd`` and ``rglru``
+    (their recurrent states)."""
+    out: dict[str, int] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(state):
+        keys = [getattr(p, "key", None) for p in path]
+        if "attn" in keys or "cross" in keys:
+            label = "kv"
+        elif keys[-1] == "conv":
+            label = "conv"
+        else:
+            label = "ssd" if "ssd" in keys else "rglru"
+        out[label] = out.get(label, 0) + leaf.size * leaf.dtype.itemsize
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -304,7 +336,8 @@ def embed_inputs(params: dict, batch: dict, cfg: ModelConfig) -> jax.Array:
             x = batch["embeds"]
         else:
             x = params["embed"][batch["inputs"]]
-        return constrain(x.astype(cfg.cdtype), ("batch", "seq", "d_model"))
+        x = _scale_embedding(x.astype(cfg.cdtype), cfg)
+        return constrain(x, ("batch", "seq", "d_model"))
 
 
 def _encoder_forward(params: dict, batch: dict, cfg: ModelConfig, *,
@@ -338,9 +371,14 @@ def forward_train(params: dict, batch: dict, cfg: ModelConfig, *,
 
 
 def _logits(params: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """float32 logits, divided by ``logits_scaling`` (no op where it is 1);
+    the loss reads them too."""
     with jax.named_scope("logits"):
         w = params["embed"].T if cfg.tie_embeddings else params["head"]
-        return (h @ w.astype(h.dtype)).astype(jnp.float32)
+        logits = (h @ w.astype(h.dtype)).astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
 
 def _ce_chunk(params: dict, h: jax.Array, targets: jax.Array,
@@ -441,7 +479,7 @@ def block_prefill(params: dict, x: jax.Array, cfg: ModelConfig,
         y, c = griffin.rglru_block_train(params["rglru"], h, cfg,
                                          return_state=True)
         entry = {"rglru": c}
-    x = x + y
+    x = _residual(x, y, cfg)
     if enc_out is not None and "cross" in params:
         hx = rms_norm(x, params["ln_x"], cfg.norm_eps)
         out, kvs = attention_train(params["cross"], hx, cfg,
@@ -532,7 +570,7 @@ def decode_step(params: dict, state: dict, batch: dict, cfg: ModelConfig
             x = batch["embeds"].astype(cfg.cdtype)
         else:
             x = params["embed"][batch["inputs"]].astype(cfg.cdtype)
-        x = constrain(x, ("batch", "seq_res", "d_model"))
+        x = constrain(_scale_embedding(x, cfg), ("batch", "seq_res", "d_model"))
 
     new_segments = []
     for seg_params, seg_state, (unit, repeats) in zip(

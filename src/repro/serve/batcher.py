@@ -114,10 +114,12 @@ class DecodeBackend:
 
     The serving driver sets ``span_log`` to its monitor's
     :class:`~repro.core.monitoring.SpanLog`, which times the steps of a
-    backend that records spans.
+    backend that records spans, and ``monitor`` to the monitor itself,
+    which takes its gauges.
     """
 
     name = "base"
+    monitor: Any = None
 
     def start_replica(self, replica: Any) -> None:
         """Allocate per-replica decode state (KV cache)."""
@@ -176,16 +178,20 @@ class JaxDecodeBackend(DecodeBackend):
     def start_replica(self, replica: Any) -> None:
         import jax
 
-        from repro.models import cache_defs, materialize
+        from repro.models import cache_defs, decode_state_bytes, materialize
 
         dev = self._placement.setdefault(
             replica.name,
             self.devices[len(self._placement) % len(self.devices)])
         if dev not in self._params:
             self._params[dev] = jax.device_put(self.params, dev)
-        self._caches[replica.name] = jax.device_put(materialize(
+        cache = self._caches[replica.name] = jax.device_put(materialize(
             cache_defs(self.cfg, self.max_batch, self.max_len),
             jax.random.PRNGKey(0)), dev)
+        if self.monitor is not None:
+            for label, n in decode_state_bytes(cache).items():
+                self.monitor.record_gauge("serve.decode_state_bytes", n,
+                                          label=label)
 
     def drop_replica(self, name: str) -> None:
         self._caches.pop(name, None)

@@ -170,8 +170,10 @@ class WrathServeDriver:
         else:
             self.backend = JaxDecodeBackend(cfg, max_batch=max_batch,
                                             seed=seed, max_len=max_len)
-        # the backend's decode-step spans go to this plane's span log
+        # the backend's decode-step spans and gauges go to this plane's
+        # monitor
         self.backend.span_log = self.monitor.spans
+        self.backend.monitor = self.monitor
         # -- continuous plane state ------------------------------------
         self.queue = RequestQueue(clock=self.clock, capacity=queue_capacity,
                                   monitor=self.monitor)

@@ -61,6 +61,7 @@ def test_all_assigned_configs_match_spec():
         "olmoe-1b-7b": (16, 2048, 16, 16, 1024, 50304),
         "mamba2-780m": (48, 1536, 48, 48, 0, 50280),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }
     for arch in ARCH_IDS:
         cfg = get_config(arch)
@@ -92,6 +93,16 @@ def test_gemma3_pattern_is_5_local_1_global():
         assert mixer == ("attn" if i % 6 == 5 else "swa")
 
 
+def test_granite4h_attention_at_5_15_25_35_in_one_scan_segment():
+    cfg = get_config("granite_4_0_h_micro")
+    kinds = cfg.block_kinds()
+    assert [i for i, (m, _) in enumerate(kinds) if m == "attn"] == [5, 15, 25, 35]
+    assert all(m == "ssd" for m, _ in kinds if m != "attn")
+    assert all(f == "dense" for _, f in kinds)
+    (unit, repeats), = cfg.scan_segments()
+    assert unit == cfg.pattern and repeats == 4
+
+
 def test_deepseek_v3_first_3_dense():
     kinds = get_config("deepseek_v3_671b").block_kinds()
     assert all(f == "dense" for _, f in kinds[:3])
@@ -101,7 +112,7 @@ def test_deepseek_v3_first_3_dense():
 # -------------------------------------------------- train/decode parity --
 @pytest.mark.parametrize("arch", ["granite_3_2b", "gemma3_27b", "mamba2_780m",
                                   "recurrentgemma_9b", "deepseek_v3_671b",
-                                  "seamless_m4t_medium"])
+                                  "seamless_m4t_medium", "granite_4_0_h_micro"])
 def test_decode_matches_train_forward(arch):
     """Token-by-token decode must reproduce the full-sequence forward."""
     cfg = fp32(get_smoke_config(arch))

@@ -307,7 +307,7 @@ class SpanLog:
     enabled, each span records its name, its start and end on
     ``time.perf_counter()`` (the clock a benchmark times steps on), the id
     of the span open around it on the same thread (its parent), and the
-    attributes it was given (``rid``, ``replica``, ``step``).  With
+    attributes it was given (``rid``, ``replica``, ``step``, ``live``).  With
     ``annotate`` each span is also a ``jax.profiler.TraceAnnotation`` of
     the same name, so it shows in a profiler trace on the device's clock.
 

@@ -131,12 +131,12 @@ def block_train(params: dict, x: jax.Array, cfg: ModelConfig, kind: BlockKind,
 
 
 def block_decode(params: dict, x: jax.Array, state: dict,
-                 layer: jax.Array | int, cfg: ModelConfig, kind: BlockKind
-                 ) -> tuple[jax.Array, dict]:
+                 layer: jax.Array | int, cfg: ModelConfig, kind: BlockKind,
+                 live: ssm.Live) -> tuple[jax.Array, dict]:
     """One block's decode step against its unit's stacked state (every
     leaf ``(L, ...)``), of which it updates layer ``layer`` in place:
-    attention writes one position, the recurrent mixers their small state
-    whole.  Returns (x, state)."""
+    attention writes one position, RG-LRU its small state whole, and the
+    SSD mixer the state of the ``live`` slots only.  Returns (x, state)."""
     mixer, _ = kind
     state = dict(state)
     with jax.named_scope(_mixer_scope(mixer)):
@@ -147,15 +147,18 @@ def block_decode(params: dict, x: jax.Array, state: dict,
         elif mixer == "mla":
             y, state["attn"] = mla_decode(params["attn"], h, state["attn"],
                                           layer, cfg)
+        elif mixer == "ssd":
+            y, state["ssd"] = ssm.ssd_block_decode(params["ssd"], h,
+                                                   state["ssd"], layer, cfg,
+                                                   live)
         else:
-            step = (ssm.ssd_block_decode if mixer == "ssd"
-                    else griffin.rglru_block_decode)
-            y, new = step(params[mixer], h,
-                          jax.tree.map(lambda s: layer_slice(s, layer),
-                                       state[mixer]), cfg)
-            state[mixer] = jax.tree.map(
+            y, new = griffin.rglru_block_decode(
+                params["rglru"], h,
+                jax.tree.map(lambda s: layer_slice(s, layer), state["rglru"]),
+                cfg)
+            state["rglru"] = jax.tree.map(
                 lambda s, n: jax.lax.dynamic_update_index_in_dim(
-                    s, n.astype(s.dtype), layer, 0), state[mixer], new)
+                    s, n.astype(s.dtype), layer, 0), state["rglru"], new)
         x = _residual(x, y, cfg)
     if "cross" in state and "cross" in params:
         h = rms_norm(x, params["ln_x"], cfg.norm_eps)
@@ -560,6 +563,9 @@ def decode_step(params: dict, state: dict, batch: dict, cfg: ModelConfig
     """One-token decode.  batch: {"inputs": (B,1) ids} or {"embeds": (B,1,d)}.
     Returns (logits, state).
 
+    A negative id marks a free slot: it is embedded as id 0, and the SSD
+    mixers leave its state as it was.  With embeds every slot is live.
+
     The layer scan carries each segment's stacked state and slices only
     the weights, so a layer updates its own part of the state where it
     lies (one position per attention layer) and no layer's state is
@@ -568,8 +574,11 @@ def decode_step(params: dict, state: dict, batch: dict, cfg: ModelConfig
     with jax.named_scope("embed"):
         if cfg.input_kind == "embeds" and "embeds" in batch:
             x = batch["embeds"].astype(cfg.cdtype)
+            live = ssm.Live.of(jnp.ones(x.shape[:1], bool))
         else:
-            x = params["embed"][batch["inputs"]].astype(cfg.cdtype)
+            ids = batch["inputs"]
+            live = ssm.Live.of(ids[:, 0] >= 0)
+            x = params["embed"][jnp.maximum(ids, 0)].astype(cfg.cdtype)
         x = constrain(_scale_embedding(x, cfg), ("batch", "seq_res", "d_model"))
 
     new_segments = []
@@ -581,7 +590,8 @@ def decode_step(params: dict, state: dict, batch: dict, cfg: ModelConfig
             st = dict(st)
             for u, kind in enumerate(_unit):
                 h, st[str(u)] = block_decode(layer_params[str(u)], h,
-                                             st[str(u)], layer, cfg, kind)
+                                             st[str(u)], layer, cfg, kind,
+                                             live)
             return (h, st), None
 
         with jax.named_scope("layers"):

@@ -9,11 +9,14 @@ conv over (x, B, C); SSD core; gated RMSNorm; out_proj.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ssd_decode import pack_aux, ssd_decode_update
 from repro.models.config import ModelConfig, SSMCfg
-from repro.models.layers import constrain, rms_norm
+from repro.models.layers import constrain, layer_slice, rms_norm
 from repro.models.spec import pdef
 
 
@@ -165,10 +168,59 @@ def ssd_block_train(params: dict, x: jax.Array, cfg: ModelConfig, *,
     return out
 
 
+class Live(NamedTuple):
+    """The decode batch's occupied slots: ``mask`` (B,) bool, and ``ids``
+    (B,) int32 holding the ``count`` live slots first, in slot order."""
+    mask: jax.Array
+    ids: jax.Array
+    count: jax.Array
+
+    @classmethod
+    def of(cls, mask: jax.Array) -> "Live":
+        return cls(mask, jnp.flatnonzero(mask, size=mask.shape[0],
+                                         fill_value=0).astype(jnp.int32),
+                   jnp.sum(mask, dtype=jnp.int32))
+
+
+def state_update_xla(state, layer, live, xh, dt, a, b, c):
+    """The SSD decode update of layer ``layer`` of the stacked state, in
+    the compute dtype; a free slot's state is kept and its ``y`` is 0."""
+    old = layer_slice(state, layer)                      # (B,H,P,N)
+    h = old.shape[1]
+    bh = jnp.repeat(b, h // b.shape[1], axis=1)          # (B,H,N)
+    ch = jnp.repeat(c, h // c.shape[1], axis=1)
+    decay = jnp.exp(dt * a[None]).astype(xh.dtype)       # (B,H)
+    upd = jnp.einsum("bhp,bhn,bh->bhpn", xh, bh, dt.astype(xh.dtype))
+    new = old * decay[..., None, None] + upd
+    new = jnp.where(live.mask[:, None, None, None], new, old)
+    y = jnp.einsum("bhpn,bhn->bhp", new, ch)
+    y = jnp.where(live.mask[:, None, None], y, 0)
+    return y, jax.lax.dynamic_update_index_in_dim(
+        state, new.astype(state.dtype), layer, 0)
+
+
+def state_update_kernel(state, layer, live, xh, dt, a, b, c, *,
+                        interpret: bool = False):
+    """The same update by ``kernels.ssd_decode`` (one group of B and C):
+    the live slots' state only, read and written once each, in place."""
+    bsz, h, p = xh.shape
+    aux = pack_aux(xh, dt, jnp.exp(dt * a[None]), b[:, 0], c[:, 0])
+    y, state = ssd_decode_update(state, layer, live.ids, live.count, aux,
+                                 interpret=interpret)
+    return y.reshape(bsz, h, p).astype(xh.dtype), state
+
+
 def ssd_block_decode(params: dict, x: jax.Array, cache: dict,
-                     cfg: ModelConfig) -> tuple[jax.Array, dict]:
-    """Single-token update.  cache: {"conv": (B, W-1, conv_dim),
-    "state": (B, H, P, N)}."""
+                     layer: jax.Array | int, cfg: ModelConfig, live: Live
+                     ) -> tuple[jax.Array, dict]:
+    """Single-token update of layer ``layer`` of the stacked cache
+    {"conv": (L, B, W-1, conv_dim), "state": (L, B, H, P, N)}, for the
+    slots ``live`` holds; a free slot's state and window are kept.
+
+    Lowered for the TPU, with one group of B and C, the state is updated
+    by the Pallas kernel ``kernels.ssd_decode``, which touches only the
+    live slots' blocks; elsewhere by the same arithmetic in XLA over
+    every slot, masked."""
     s: SSMCfg = cfg.ssm  # type: ignore[assignment]
     dims = ssm_dims(cfg)
     bsz = x.shape[0]
@@ -179,21 +231,27 @@ def ssd_block_decode(params: dict, x: jax.Array, cache: dict,
         zxbcdt, [dims["d_inner"], 2 * dims["d_inner"],
                  2 * dims["d_inner"] + 2 * g * n], axis=-1)
     conv_in = jnp.concatenate([xin, bc_in], axis=-1)
+    conv = layer_slice(cache["conv"], layer)
     conv_out, conv_state = _causal_conv(conv_in, params["conv_w"],
-                                        params["conv_b"], state=cache["conv"])
+                                        params["conv_b"], state=conv)
+    conv_state = jnp.where(live.mask[:, None, None], conv_state, conv)
     xin, b_in, c_in = jnp.split(conv_out, [dims["d_inner"],
                                            dims["d_inner"] + g * n], axis=-1)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                          + params["dt_bias"][None, None])[:, 0]   # (B,H)
     a = -jnp.exp(params["a_log"])                        # (H,)
     xh = xin.reshape(bsz, h, p)
-    bh = jnp.repeat(b_in.reshape(bsz, g, n), h // g, axis=1)      # (B,H,N)
-    ch = jnp.repeat(c_in.reshape(bsz, g, n), h // g, axis=1)
-    decay = jnp.exp(dt * a[None]).astype(x.dtype)        # (B,H)
-    upd = jnp.einsum("bhp,bhn,bh->bhpn", xh, bh, dt.astype(x.dtype))
-    state = cache["state"] * decay[..., None, None] + upd
-    y = jnp.einsum("bhpn,bhn->bhp", state, ch)
+    args = (cache["state"], layer, live, xh, dt, a,
+            b_in.reshape(bsz, g, n), c_in.reshape(bsz, g, n))
+    if g == 1:
+        y, state = jax.lax.platform_dependent(
+            *args, tpu=state_update_kernel, default=state_update_xla)
+    else:
+        y, state = state_update_xla(*args)
     y = y + params["d_skip"].astype(x.dtype)[None, :, None] * xh
     y = y.reshape(bsz, 1, dims["d_inner"])
     y = rms_norm(y * jax.nn.silu(z), params["norm"], cfg.norm_eps)
-    return y @ params["out_proj"], {"conv": conv_state, "state": state}
+    return y @ params["out_proj"], {
+        "conv": jax.lax.dynamic_update_index_in_dim(
+            cache["conv"], conv_state.astype(cache["conv"].dtype), layer, 0),
+        "state": state}

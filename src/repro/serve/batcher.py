@@ -1,11 +1,14 @@
 """Continuous (dynamic) batching: slot-structured decode state per replica.
 
 The saxml servable-model idiom: each replica runs ONE padded decode
-program at a fixed batch size (``max_batch``).  The program's cost is set
-by the padding, not the occupancy, so the throughput lever is *slot
-utilization*: a finished request vacates its slot at the step boundary
-and the next queued request moves in immediately — no waiting for the
-rest of the batch, no head-of-line blocking behind the longest request.
+program at a fixed batch size (``max_batch``).  Its weights, products and
+attention cache are paid for by the padding, whatever the occupancy; only
+the recurrent (Mamba-2) state is read and written for the occupied slots
+alone, on the TPU (a free slot's token id is -1).  So the throughput
+lever is *slot utilization*: a finished request vacates its slot at the
+step boundary and the next queued request moves in immediately — no
+waiting for the rest of the batch, no head-of-line blocking behind the
+longest request.
 
 Two decode backends share the slot protocol:
 
@@ -223,9 +226,13 @@ class JaxDecodeBackend(DecodeBackend):
                 f"replica {replica.name} lost its decode state",
                 node=replica.name)
         spans = self.span_log
-        with spans.span("serve.step", replica=replica.name):
+        # ``live``: the slots whose state the step updates
+        live = (sum(tok is not None for tok in inputs) if spans.enabled
+                else None)
+        with spans.span("serve.step", replica=replica.name, live=live):
             with spans.span("serve.step.pack"):
-                toks = np.zeros((self.max_batch, 1), np.int32)
+                # a free slot's id is -1: the program leaves its state be
+                toks = np.full((self.max_batch, 1), -1, np.int32)
                 for i, tok in enumerate(inputs):
                     if tok is not None:
                         toks[i, 0] = tok

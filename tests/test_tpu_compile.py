@@ -244,34 +244,83 @@ def test_granite_decode_step_updates_its_state_in_place(one_chip):
     assert writes == 2                         # K and V, in the layer loop
 
 
-def test_granite4h_decode_step_carries_both_states_in_place(one_chip):
+@pytest.fixture(scope="module")
+def ssd_decode_programs(one_chip):
+    """The decode steps of the two configurations with Mamba-2 layers at
+    published widths and the benchmark's 96 slots of 512 positions, as
+    the serving backend jits them, compiled for one v5e chip: per
+    configuration its compiled program, abstract cache and the number of
+    weight leaves (the cache's parameters follow them)."""
+    out = {}
+    for arch in ("mamba2-780m", "granite-4-0-h-micro"):
+        cfg = get_config(arch)
+        params = _on(one_chip, abstract(param_defs(cfg)))
+        cache = _on(one_chip, abstract(cache_defs(cfg, 96, 512)))
+        batch = _on(one_chip,
+                    {"inputs": jax.ShapeDtypeStruct((96, 1), jnp.int32)})
+        compiled = decode_program(cfg).lower(params, cache, batch).compile()
+        out[arch] = compiled, cache, len(jax.tree.leaves(params))
+    return out
+
+
+def test_granite4h_decode_step_carries_both_states_in_place(
+        ssd_decode_programs):
     """granite-4.0-h-micro at published widths and the benchmark's 96
     slots of 512 positions, as the serving backend jits it: 36 Mamba-2
     layers' SSD state and window and 4 attention layers' K/V carried in
     one scan of the 10-layer unit, all aliased, none copied.  This
-    compile: 10.50 GB of arguments, 4.12 GB aliased, 0.84 GB of
-    temporaries (one layer's SSD intermediates, 0.1 GB each)."""
+    compile: 10.50 GB of arguments, 4.12 GB aliased, 0.028 GB of
+    temporaries (with the SSD state updated in XLA over every slot, 0.84
+    GB: one layer's SSD intermediates, 0.1 GB each)."""
     _bench_on_path()
     from bench import scope_reduce
 
-    cfg = get_config("granite-4-0-h-micro")
-    params = _on(one_chip, abstract(param_defs(cfg)))
-    cache = _on(one_chip, abstract(cache_defs(cfg, 96, 512)))
-    batch = _on(one_chip, {"inputs": jax.ShapeDtypeStruct((96, 1), jnp.int32)})
-    compiled = decode_program(cfg).lower(params, cache, batch).compile()
+    compiled, cache, n_params = ssd_decode_programs["granite-4-0-h-micro"]
     _fits(compiled)
     mem = compiled.memory_analysis()
     state_bytes = sum(leaf.size * leaf.dtype.itemsize
                       for leaf in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= state_bytes
-    assert mem.temp_size_in_bytes < 1.0e9
-    n_params = len(jax.tree.leaves(params))
+    assert mem.temp_size_in_bytes < 0.1e9
     scopes = scope_reduce.hlo_scopes(
         compiled.as_text(),
         range(n_params, n_params + len(jax.tree.leaves(cache))))
     classes = set(scopes.values())
     assert {"ssd", "attn", "kv_write", "ffn", "logits"} <= classes
     assert "layer_state" not in classes
+
+
+@pytest.mark.parametrize("arch", ["mamba2-780m", "granite-4-0-h-micro"])
+def test_ssd_decode_step_updates_the_state_by_the_kernel_in_place(
+        ssd_decode_programs, arch):
+    # the SSD state of every Mamba-2 layer is updated by the Pallas kernel
+    # (one per unit position of the layer scan), under the ``ssd`` scope,
+    # into the donated state: aliased whole, no op copies it or makes a
+    # layer's slab of it, and the temporaries stay small (mamba2-780m
+    # 0.0004 GB, granite-4.0-h-micro 0.028 GB in this compile)
+    _bench_on_path()
+    from bench import scope_reduce
+
+    compiled, cache, n_params = ssd_decode_programs[arch]
+    _fits(compiled)
+    mem = compiled.memory_analysis()
+    states = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+              if getattr(path[-1], "key", None) == "state"]
+    assert mem.alias_size_in_bytes >= sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
+    assert mem.temp_size_in_bytes < 0.1e9
+    text = compiled.as_text()
+    kernels = [name for _, name, _, op, *_ in _instructions(text)
+               if op == "custom-call" and "ssd_decode_update" in name]
+    units = {s.shape for s in states}
+    assert len(kernels) == len(states) and units
+    scopes = scope_reduce.hlo_scopes(
+        text, range(n_params, n_params + len(jax.tree.leaves(cache))))
+    assert {scopes[k] for k in kernels} == {"ssd"}
+    slabs = units | {s[1:] for s in units} | {(1,) + s[1:] for s in units}
+    for _, name, made, op, *_ in _instructions(text):
+        if slabs & set(made):
+            assert op in _ROUTING | {"custom-call"}, (name, op, made)
 
 
 def test_supervisor_grad_fn_granite_4_layers(one_chip, tmp_path):
